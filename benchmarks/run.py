@@ -104,4 +104,7 @@ if __name__ == "__main__":
     ap.add_argument("--json", default="", metavar="PATH",
                     help="write captured rows to a BENCH_*.json artifact")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main(smoke=args.smoke, json_path=args.json)

@@ -3,6 +3,7 @@
     PYTHONPATH=src python examples/quickstart.py
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -35,8 +36,6 @@ def main():
     #    for every kernel. compute_dtype="float64" (needs x64) turns the
     #    engine into its own verification oracle: the f64-accumulated
     #    batched matmul is the reference the fp32 run is judged against.
-    from jax.experimental import enable_x64
-
     from repro.kernels import use_policy
 
     rng = np.random.default_rng(0)
@@ -46,7 +45,7 @@ def main():
     for scheme in ("naive", "kahan"):
         with use_policy(scheme=scheme, blocks=(16, 128, 256)):
             c32[scheme] = np.asarray(ops.batched_matmul(A, B), np.float64)
-    with enable_x64():
+    with jax.enable_x64(True):
         with use_policy(scheme="kahan", compute_dtype="float64",
                         blocks=(16, 128, 256)):
             c64 = np.asarray(ops.batched_matmul(A, B))

@@ -209,12 +209,14 @@ def _v(target, rule: str, message: str) -> Violation:
 # Built-in trace rules
 # ---------------------------------------------------------------------------
 
-# shard_map traces spell the cross-device sum ``psum2``; pmap traces and
-# reduce_scatter spell ``psum`` / ``psum_scatter``. All are re-associable
-# backend reductions — all are off-contract for float payloads.
-_PSUM_PRIMS = frozenset(("psum", "psum2", "psum_scatter"))
+# shard_map traces spell the cross-device sum ``psum_invariant``; pmap
+# traces spell ``psum``, and ``lax.psum_scatter`` traces as
+# ``reduce_scatter``. All are re-associable backend reductions — all are
+# off-contract for float payloads.
+_PSUM_PRIMS = frozenset(("psum", "psum_invariant", "reduce_scatter"))
+# ``jax.debug.print`` traces as ``debug_print``, not ``debug_callback``.
 _CALLBACK_PRIMS = frozenset(
-    ("pure_callback", "io_callback", "debug_callback"))
+    ("pure_callback", "io_callback", "debug_callback", "debug_print"))
 
 
 def _check_no_raw_psum(target, art) -> Iterator[Violation]:
@@ -293,9 +295,9 @@ def _check_no_host_callback(target, art) -> Iterator[Violation]:
     for eqn, path in iter_eqns(art.jaxpr):
         if eqn.primitive.name in _CALLBACK_PRIMS:
             yield _v(target, "trace-no-host-callback",
-                     f"{eqn.primitive.name} primitive in a serving trace "
-                     f"(at {path or 'top level'}) — a host round-trip on "
-                     f"every execution")
+                     f"host callback ({eqn.primitive.name}) in a serving "
+                     f"trace (at {path or 'top level'}) — a host "
+                     f"round-trip on every execution")
 
 
 def _check_barrier_survives_fusion(target, art) -> Iterator[Violation]:
@@ -377,8 +379,8 @@ for _rule in (
         checker=_check_no_host_callback,
         fix_hint="drop jax.debug.print / callbacks from serving bodies; "
                  "emit at the engine's host-side points instead",
-        doc="no pure_callback/io_callback/debug_callback primitives in "
-            "serving traces",
+        doc="no pure_callback/io_callback/debug_callback/debug_print "
+            "primitives in serving traces",
     ),
     TraceRule(
         id="trace-barrier-survives-fusion",

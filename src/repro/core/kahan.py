@@ -47,8 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax import tree_util
 
-from repro.core import compat
-
 
 Array = jax.Array
 
@@ -432,6 +430,7 @@ def compensated_psum_scalar(s: Array, c: Array, axis_name: str) -> Tuple[Array, 
     init = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
     # under shard_map the gathered xs are "varying" over axis_name; the
     # carry must match that manual-axes type
-    init = compat.pcast_varying(init, axis_name)
+    init = jax.tree.map(
+        lambda t: jax.lax.pcast(t, (axis_name,), to="varying"), init)
     (rs, rc), _ = jax.lax.scan(body, init, (ss, cs))
     return rs, rc

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.core.kahan import compensated_psum_scalar, kahan_step
 from repro.kernels.engine import (
     Accumulator,
@@ -96,8 +95,8 @@ def sharded_asum(mesh: Mesh, x: jax.Array, *, axis: str = "data",
                                interpret=interpret,
                                compute_dtype=compute_dtype)
     reduce = _sharded_reduce(axis, eng.sum_accumulators)
-    return compat.shard_map(reduce, mesh=mesh, in_specs=P(axis),
-                            out_specs=P(), check_vma=False)(x)
+    return jax.shard_map(reduce, mesh=mesh, in_specs=P(axis),
+                         out_specs=P(), check_vma=False)(x)
 
 
 def sharded_dot(mesh: Mesh, a: jax.Array, b: jax.Array, *,
@@ -111,8 +110,8 @@ def sharded_dot(mesh: Mesh, a: jax.Array, b: jax.Array, *,
                                interpret=interpret,
                                compute_dtype=compute_dtype)
     reduce = _sharded_reduce(axis, eng.dot_accumulators)
-    return compat.shard_map(reduce, mesh=mesh, in_specs=(P(axis), P(axis)),
-                            out_specs=P(), check_vma=False)(a, b)
+    return jax.shard_map(reduce, mesh=mesh, in_specs=(P(axis), P(axis)),
+                         out_specs=P(), check_vma=False)(a, b)
 
 
 def sharded_matmul(mesh: Mesh, a: jax.Array, b: jax.Array, *,
@@ -145,9 +144,9 @@ def sharded_matmul(mesh: Mesh, a: jax.Array, b: jax.Array, *,
         cs = jax.lax.all_gather(acc.c, axis)
         return merge_accumulator_grids(ss, cs)[:m, :n]
 
-    return compat.shard_map(reduce, mesh=mesh,
-                            in_specs=(P(None, axis), P(axis, None)),
-                            out_specs=P(), check_vma=False)(a, b)
+    return jax.shard_map(reduce, mesh=mesh,
+                         in_specs=(P(None, axis), P(axis, None)),
+                         out_specs=P(), check_vma=False)(a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -161,8 +160,8 @@ def deterministic_mean(mesh: Mesh, values: jax.Array, axis: str = "data",
     Gathers the (value, comp) pairs and folds them in device order with
     two-sum — the distributed form of the paper's compensated reduction.
     """
-    @compat.shard_map(mesh=mesh, in_specs=P(axis), out_specs=P(),
-                      check_vma=False)  # fold result replicated by construction
+    @jax.shard_map(mesh=mesh, in_specs=P(axis), out_specs=P(),
+                   check_vma=False)  # fold result replicated by construction
     def reduce(v):
         s, c = kahan_step(jnp.zeros(()), jnp.zeros(()), v[0])
         rs, rc = compensated_psum_scalar(s, c, axis)

@@ -26,7 +26,8 @@ the ``acc / l`` division) happens in ``CompensatedReduction``, which also
 owns Sq/Skv padding, compute-dtype promotion, and interpret resolution.
 The public ``flash_attention`` below is a thin policy-resolving veneer
 over the engine; ``kernels.ref.flash_attention_ref`` traces the SAME
-scheme callables block-for-block, so kernel-vs-oracle equality is bitwise.
+scheme callables block-for-block, so kernel-vs-oracle equality is bitwise
+in interpret mode (on a TPU it is a tolerance; see ``flash_block_update``).
 
 Layout: inputs [BH, S, dh] (batch*heads flattened by the caller); grid
 (BH, q_blocks, k_blocks), k innermost ("arbitrary"); per-(bh, q-block)
@@ -77,19 +78,28 @@ def flash_block_update(scheme: CompensationScheme, q, k, v, m_old,
                        l_s, l_c, a_s, a_c, *, qb, kb, step, block_q: int,
                        block_k: int, kv_len: int, causal: bool,
                        scale: float, compute_dtype=jnp.float32,
-                       q_off=None):
+                       q_off=None, pin: bool = True):
     """ONE k-block fold of the online-softmax state — the shared body.
 
     Traced by BOTH the Pallas kernel (block refs) and the jnp oracle
     (array slices), exactly like the scheme callables are shared by the
     dot kernels and their oracles — kernel-vs-oracle bitwise equality by
-    construction. Every fusion-sensitive op (dot, mul, reduce, exp,
-    select) is pinned behind ``lax.optimization_barrier``: XLA CPU
-    contracts mul+add chains into FMAs, inlines exp into consumer loops
-    with a different rounding path, and rematerializes producers across
-    fusion boundaries — all decisions that vary with the surrounding
-    program and would otherwise let the same math round differently in
-    the kernel and the oracle.
+    construction. With ``pin`` (the default) every fusion-sensitive op
+    (dot, mul, reduce, exp, select) is pinned behind
+    ``lax.optimization_barrier``: XLA CPU contracts mul+add chains into
+    FMAs, inlines exp into consumer loops with a different rounding
+    path, and rematerializes producers across fusion boundaries — all
+    decisions that vary with the surrounding program and would otherwise
+    let the same math round differently in the kernel and the oracle.
+
+    The Pallas kernels pass ``pin=interpret``: interpret mode and the
+    oracle both run on XLA and keep their barriers, so there the
+    equality stays bitwise. Mosaic has no lowering for
+    ``optimization_barrier``, so a compiled TPU kernel traces the body
+    unpinned. Kernel (Mosaic) and oracle (XLA) are then different
+    compilers, and on a TPU the kernel agrees with
+    ``ref.flash_attention_ref`` to a tolerance (``chip_smoke.py`` holds
+    both to ``FLASH_TOL``), not bitwise.
 
     Inputs are one block each: q [bq, dh]; k/v [bk, dh]; running stats
     m_old/l/l_c [bq, 1], a/a_c [bq, dh]. Returns the updated
@@ -105,8 +115,11 @@ def flash_block_update(scheme: CompensationScheme, q, k, v, m_old,
     is identical. ``None`` (the default) keeps the traced program of
     the non-offset paths byte-for-byte unchanged.
     """
-    barrier = jax.lax.optimization_barrier
+    barrier = jax.lax.optimization_barrier if pin else (lambda x: x)
+    # HIGHEST on both products: a TPU's default contraction precision
+    # rounds f32 operands to one bf16 pass
     s = barrier(jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),  # contract: allow-no-uncompensated-reduction(flash scores; compute_dtype over head_dim terms, block-local)
+                                    precision=jax.lax.Precision.HIGHEST,
                                     preferred_element_type=compute_dtype))
     s = barrier(s * scale)
     q_pos = qb * block_q + jax.lax.broadcasted_iota(
@@ -125,6 +138,7 @@ def flash_block_update(scheme: CompensationScheme, q, k, v, m_old,
     p = barrier(jnp.exp(barrier(s - m_new)))          # [bq, bk]
     p_sum = barrier(rowsum_tree(p))
     pv = barrier(jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),  # contract: allow-no-uncompensated-reduction(flash PV block product; the scheme accumulator fold below carries the compensation)
+                                     precision=jax.lax.Precision.HIGHEST,
                                      preferred_element_type=compute_dtype))
     # rescale value AND comp, then fold this k-block's contribution
     # through the scheme's accumulator update.
@@ -192,7 +206,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, ls_out, lc_out, as_out, ac_out,
                   m_scr, l_scr, lc_scr, acc_scr, accc_scr, *,
                   scheme: CompensationScheme, causal: bool, block_q: int,
                   block_k: int, k_steps: int, kv_len: int, scale: float,
-                  compute_dtype=jnp.float32):
+                  compute_dtype=jnp.float32, interpret: bool):
     kb = pl.program_id(2)
 
     @pl.when(kb == 0)
@@ -211,7 +225,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, ls_out, lc_out, as_out, ac_out,
         scheme, q, k, v, m_scr[...], l_scr[...], lc_scr[...],
         acc_scr[...], accc_scr[...], qb=pl.program_id(1), kb=kb, step=kb,
         block_q=block_q, block_k=block_k, kv_len=kv_len, causal=causal,
-        scale=scale, compute_dtype=compute_dtype)
+        scale=scale, compute_dtype=compute_dtype, pin=interpret)
     l_scr[...] = l_s
     lc_scr[...] = l_c
     acc_scr[...] = a_s
@@ -258,7 +272,7 @@ def flash_accumulators(q, k, v, *, block_q, block_k,
     kernel = functools.partial(
         _flash_kernel, scheme=scheme, causal=causal, block_q=block_q,
         block_k=block_k, k_steps=grid[2], kv_len=kv_len, scale=scale,
-        compute_dtype=compute_dtype)
+        compute_dtype=compute_dtype, interpret=interpret)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -297,7 +311,7 @@ def _flash_chunk_kernel(off_ref, q_ref, k_ref, v_ref, ls_out, lc_out,
                         accc_scr, *, scheme: CompensationScheme,
                         block_q: int, block_k: int, k_steps: int,
                         kv_len: int, scale: float,
-                        compute_dtype=jnp.float32):
+                        compute_dtype=jnp.float32, interpret: bool):
     """Chunked-prefill grid body: ``_flash_kernel`` plus a traced query
     offset read from SMEM. Queries live at absolute positions
     ``q_off + i``; masking is always causal on those absolute positions,
@@ -321,7 +335,8 @@ def _flash_chunk_kernel(off_ref, q_ref, k_ref, v_ref, ls_out, lc_out,
         scheme, q, k, v, m_scr[...], l_scr[...], lc_scr[...],
         acc_scr[...], accc_scr[...], qb=pl.program_id(1), kb=kb, step=kb,
         block_q=block_q, block_k=block_k, kv_len=kv_len, causal=True,
-        scale=scale, compute_dtype=compute_dtype, q_off=off_ref[0, 0])
+        scale=scale, compute_dtype=compute_dtype, q_off=off_ref[0, 0],
+        pin=interpret)
     l_scr[...] = l_s
     lc_scr[...] = l_c
     acc_scr[...] = a_s
@@ -373,7 +388,7 @@ def flash_chunk_accumulators(q, k, v, q_off, *, block_q, block_k,
     kernel = functools.partial(
         _flash_chunk_kernel, scheme=scheme, block_q=block_q,
         block_k=block_k, k_steps=grid[2], kv_len=kv_len, scale=scale,
-        compute_dtype=compute_dtype)
+        compute_dtype=compute_dtype, interpret=interpret)
     return pl.pallas_call(
         kernel,
         grid=grid,

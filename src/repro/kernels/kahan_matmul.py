@@ -63,7 +63,10 @@ def _matmul_kernel(a_ref, b_ref, s_out, c_out, s_acc, c_acc, *,
 
     a = a_ref[...].reshape(s_acc.shape[0], -1).astype(compute_dtype)
     b = b_ref[...].reshape(-1, s_acc.shape[1]).astype(compute_dtype)
-    prod = jnp.dot(a, b, preferred_element_type=compute_dtype)  # contract: allow-no-uncompensated-reduction(block inner product; the scheme.update fold below carries the compensation)
+    # HIGHEST: on a TPU the default contraction precision rounds f32
+    # operands to one bf16 pass, far outside the compute dtype's bound
+    prod = jnp.dot(a, b, preferred_element_type=compute_dtype,  # contract: allow-no-uncompensated-reduction(block inner product; the scheme.update fold below carries the compensation)
+                   precision=jax.lax.Precision.HIGHEST)
     s, c = scheme.update(s_acc[...], c_acc[...], prod, k)
     s_acc[...] = s
     c_acc[...] = c

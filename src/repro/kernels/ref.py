@@ -140,7 +140,8 @@ def matmul_ref(a: jax.Array, b: jax.Array, bk: int = 512,
         s, c = carry
         at, bt, g = xs
         prod = jnp.dot(at.astype(cdt), bt.astype(cdt),  # contract: allow-no-uncompensated-reduction(oracle block product; scheme.update carries the compensation, mirrors the kernel)
-                       preferred_element_type=cdt)
+                       preferred_element_type=cdt,
+                       precision=jax.lax.Precision.HIGHEST)
         return sch.update(s, c, prod, g), None
 
     init = (jnp.zeros((m, n), cdt), jnp.zeros((m, n), cdt))

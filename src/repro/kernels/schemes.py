@@ -91,7 +91,9 @@ def resolve_compute_dtype(spec):
     None resolves the ambient policy's ``compute_dtype``. Unsupported
     dtypes FAIL FAST with the supported menu; float64 additionally
     requires x64 to be enabled (otherwise jax silently truncates every
-    array to fp32 and the "f64 accumulate" would be a lie).
+    array to fp32 and the "f64 accumulate" would be a lie), and is
+    refused on a TPU backend, whose Mosaic kernels have no 64-bit float
+    type (XLA would reject the program only after tracing it).
     """
     if spec is None:
         return current_policy().compute_dtype  # already validated by Policy
@@ -100,11 +102,17 @@ def resolve_compute_dtype(spec):
         raise ValueError(
             f"compute_dtype must be one of {list(SUPPORTED_COMPUTE_DTYPES)}; "
             f"got {dt.name!r}")
-    if dt == jnp.dtype("float64") and not jax.config.jax_enable_x64:
-        raise ValueError(
-            "compute_dtype='float64' requires x64 mode (enable it with "
-            "jax.config.update('jax_enable_x64', True) or the "
-            "jax.experimental.enable_x64() context manager)")
+    if dt == jnp.dtype("float64"):
+        if jax.default_backend() == "tpu":
+            raise ValueError(
+                "compute_dtype='float64' cannot run on a TPU backend "
+                "(no 64-bit float in Mosaic kernels); choose one of "
+                "float32 | bfloat16")
+        if not jax.config.jax_enable_x64:
+            raise ValueError(
+                "compute_dtype='float64' requires x64 mode (enable it with "
+                "jax.config.update('jax_enable_x64', True) or the "
+                "jax.enable_x64(True) context manager)")
     return dt
 
 #: pairwise cascade interval: the primary accumulator folds into the

@@ -24,7 +24,6 @@ import jax           # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import SHAPES, get_config, list_archs, shape_applicable  # noqa: E402
-from repro.core import compat  # noqa: E402
 from repro.distributed import sharding as shd  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.specs import Cell, build_cell  # noqa: E402
@@ -94,7 +93,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             for spec, shapes in zip(cell.out_specs, out_shapes))
 
     t0 = time.time()
-    with compat.set_mesh(mesh), shd.activation_rules(mesh, rules):
+    with jax.set_mesh(mesh), shd.activation_rules(mesh, rules):
         jitted = jax.jit(cell.step_fn, in_shardings=in_shardings,
                          out_shardings=out_shardings)
         lowered = jitted.lower(*cell.args)

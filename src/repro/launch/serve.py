@@ -50,12 +50,13 @@ tokens, admission stalls on page exhaustion).
 """
 
 import argparse
-from typing import List, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.configs import get_config, get_smoke
+from repro.configs import ArchConfig, get_config, get_smoke
 from repro.kernels import Policy, schemes
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import EngineConfig, InferenceEngine, Request, SamplingParams
 
 
@@ -95,7 +96,8 @@ def parse_trace(spec: str, default_temp: float,
     return cells
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's command line (also parsed by ``chip_smoke.py``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -151,7 +153,8 @@ def main():
                          "--kv-layout paged)")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0,
-                    help="prompt-content RNG seed")
+                    help="seed of the prompt contents and of the random "
+                         "weights")
     ap.add_argument("--stats", action="store_true",
                     help="print compensated per-request logit norms")
     ap.add_argument("--scheme", default="kahan",
@@ -163,10 +166,30 @@ def main():
                     help="accumulator-group count of the Pallas kernels")
     ap.add_argument("--compute-dtype", default="float32",
                     help="accumulate dtype for the compensated kernels "
-                         "(float32 | bfloat16 | float64 — f64 needs x64; "
-                         "unsupported dtypes fail fast with the menu)")
-    args = ap.parse_args()
+                         "(float32 | bfloat16 | float64 — f64 needs x64 "
+                         "and is refused on a TPU; unsupported dtypes fail "
+                         "fast with the menu)")
+    return ap
 
+
+class Serving(NamedTuple):
+    """What ``build_serving`` makes from the command line."""
+
+    cfg: ArchConfig
+    cells: List[Tuple[int, int, int, float]]
+    requests: List[Request]
+    arrivals: List[int]
+    engine: InferenceEngine
+
+
+def build_serving(args: argparse.Namespace,
+                  cfg: Optional[ArchConfig] = None) -> Serving:
+    """Validate the parsed flags and build the requests and the engine.
+
+    ``cfg`` overrides the config that ``--arch`` / ``--smoke`` select
+    (e.g. the same widths with ``kahan_attention=True``). The weights are
+    random, drawn from ``--seed``.
+    """
     if args.prefill_mode not in ("scan", "flash"):
         # parse-boundary validation, same convention as the trace cells:
         # the bad flag names itself here, not inside EngineConfig or a
@@ -191,7 +214,8 @@ def main():
 
     policy = Policy(scheme=args.scheme, unroll=args.unroll,
                     compute_dtype=args.compute_dtype)
-    cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
+    if cfg is None:
+        cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     max_len = args.max_len or max(p + n for _, p, n, _ in cells)
     if args.kv_layout == "paged" and max_len % args.page_size:
         # EngineConfig requires max_len % page_size == 0; a fitted
@@ -226,7 +250,15 @@ def main():
                           kv_layout=args.kv_layout,
                           page_size=args.page_size,
                           num_pages=args.num_pages or None,
-                          prefix_cache=args.prefix_cache))
+                          prefix_cache=args.prefix_cache),
+        seed=args.seed)
+    return Serving(cfg, cells, requests, arrivals, engine)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg, cells, requests, arrivals, engine = build_serving(args)
     if args.kv_layout == "paged" and engine.kv_layout == "dense":
         print(f"# kv-layout 'paged' requested but family {cfg.family!r} "
               f"has no pageable KV leaf (recurrent/ring state only) — "

@@ -28,6 +28,7 @@ import jax        # noqa: E402
 
 from repro.configs import get_config, get_smoke  # noqa: E402
 from repro.data import DataConfig, SyntheticLM   # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.optim import AdamWConfig              # noqa: E402
 from repro.train import TrainConfig, Trainer     # noqa: E402
 
@@ -46,6 +47,7 @@ def main():
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    enable_compile_cache()
     if os.environ.get("COORDINATOR_ADDRESS"):
         jax.distributed.initialize()
 

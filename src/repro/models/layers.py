@@ -672,9 +672,7 @@ def activation_sq_norm(x: jax.Array, *, scheme=None, mesh=None,
     flat = x.reshape(x.shape[0], -1).astype(jnp.float32)
     sq = flat * flat
     if mesh is not None:
-        from repro.core import compat
-
-        return compat.shard_map(
+        return jax.shard_map(
             eng.batched_asum, mesh=mesh, in_specs=P(axis),
             out_specs=P(axis), check_vma=False)(sq)
     return eng.batched_asum(sq)

@@ -67,22 +67,19 @@ def test_no_raw_psum_fires_on_dynamically_constructed_psum():
     # the AST rule resolves names structurally — a psum assembled at
     # runtime never matches it...
     src = ("import jax\n"
-           "from repro.core import compat\n"
            "def reduce_all(mesh, x):\n"
            "    op = getattr(jax.lax, 'p' + 'sum')\n"
-           "    f = compat.shard_map(lambda s: op(s, 'data'), mesh=mesh,\n"
-           "                         in_specs=None, out_specs=None)\n"
+           "    f = jax.shard_map(lambda s: op(s, 'data'), mesh=mesh,\n"
+           "                      in_specs=None, out_specs=None)\n"
            "    return f(x)\n")
     ast_report = lint_source(src, "distributed/x.py",
                              rule_ids=["no-raw-psum"])
     assert ast_report.violations == []
 
     # ...but the primitive is right there in the traced program.
-    from repro.core import compat
-
     op = getattr(jax.lax, "p" + "sum")
-    f = compat.shard_map(lambda s: op(s, "data"), mesh=targets._mesh(),
-                         in_specs=P("data"), out_specs=P())
+    f = jax.shard_map(lambda s: op(s, "data"), mesh=targets._mesh(),
+                      in_specs=P("data"), out_specs=P())
     art = targets.TraceArtifact(jaxpr=jax.make_jaxpr(f)(_sds((4,))))
     found = _fired("trace-no-raw-psum", ("sharded",), art)
     assert found, "dynamic psum escaped the trace rule"

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import ecm, numerics
 from repro.kernels import ops, ref, schemes
@@ -32,7 +31,7 @@ def test_f64_ladder_strictly_improves_naive(gendot):
     a, b, exact, cond = gendot
     err32 = numerics.relative_error(
         float(ops.dot(a, b, scheme="naive", unroll=1)), exact)
-    with enable_x64():
+    with jax.enable_x64(True):
         err64 = numerics.relative_error(
             float(ops.dot(a, b, scheme="naive", unroll=1,
                           compute_dtype="float64")), exact)
@@ -42,7 +41,7 @@ def test_f64_ladder_strictly_improves_naive(gendot):
 @pytest.mark.parametrize("name", ["kahan", "dot2"])
 def test_f64_compensated_within_apriori_bound(gendot, name):
     a, b, exact, cond = gendot
-    with enable_x64():
+    with jax.enable_x64(True):
         got = float(ops.dot(a, b, scheme=name, unroll=1,
                             compute_dtype="float64"))
     err = numerics.relative_error(got, exact)
@@ -53,7 +52,7 @@ def test_f64_compensated_within_apriori_bound(gendot, name):
 @pytest.mark.parametrize("name", ["naive", "kahan", "dot2"])
 def test_f64_kernel_matches_oracle_bitwise(gendot, name):
     a, b, _, _ = gendot
-    with enable_x64():
+    with jax.enable_x64(True):
         got = float(ops.dot(a, b, scheme=name, unroll=1,
                             compute_dtype="float64"))
         want = float(ref.dot_ref(a, b, scheme=name, rows=8,
@@ -140,6 +139,21 @@ def test_f64_without_x64_fails_fast():
         pytest.skip("x64 globally enabled")
     with pytest.raises(ValueError, match="x64"):
         Policy(compute_dtype="float64")
+
+
+def test_f64_on_tpu_backend_fails_fast_with_menu(monkeypatch):
+    """A TPU backend has no 64-bit float in Mosaic: f64 accumulate is
+    refused at the boundary (even with x64 on), naming what can run."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.enable_x64(True):
+        for call in (lambda: Policy(compute_dtype="float64"),
+                     lambda: CompensatedReduction(compute_dtype="float64")):
+            with pytest.raises(ValueError, match="TPU") as ei:
+                call()
+            assert "float32 | bfloat16" in str(ei.value)
+        # the supported dtypes still resolve on that backend
+        assert Policy(compute_dtype="bfloat16").compute_dtype == \
+            jnp.dtype("bfloat16")
 
 
 def test_ecm_tables_model_the_dtype_axis():

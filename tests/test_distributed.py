@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.distributed import sharding as shd
 from repro.distributed import compression as comp
 from repro.perf import hlo_analysis
@@ -121,7 +120,7 @@ def test_compressed_psum_single_device():
 
     @jax.jit
     def run(x):
-        return compat.shard_map(
+        return jax.shard_map(
             lambda v: comp.compressed_psum(v, "data"),
             mesh=mesh, in_specs=P(), out_specs=P())(x)
 

@@ -7,7 +7,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_smoke
-from repro.core import compat
 from repro.train import ServeConfig, Server
 
 
@@ -54,7 +53,7 @@ def test_compensated_psum_scalar_single_device():
 
     @jax.jit
     def run(s, c):
-        return compat.shard_map(
+        return jax.shard_map(
             lambda a, b: compensated_psum_scalar(a[0], b[0], "data"),
             mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
             check_vma=False)(  # fold result is replicated by construction

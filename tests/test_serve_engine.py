@@ -787,3 +787,54 @@ def test_parse_trace_validation():
         parse_trace("0:4:2:-0.5", 0.0)
     with pytest.raises(ValueError, match="want arrival"):
         parse_trace("0:4", 0.0)
+
+
+def test_launcher_build_serving_honours_flags_and_cfg_override():
+    """``build_serving`` is the one engine construction the launcher and
+    ``chip_smoke.py`` share: flags reach the EngineConfig, the trace
+    fits max_len, the weights come from ``--seed``, and a passed config
+    (here ``kahan_attention=True``) replaces the ``--arch`` one."""
+    from repro.configs import get_smoke
+    from repro.launch import serve as launch
+
+    argv = ["--arch", "olmo-1b", "--smoke", "--trace", "0:5:3,2:9:2",
+            "--max-slots", "2", "--stats", "--prefill-chunk", "4",
+            "--prefill-mode", "flash", "--seed", "3"]
+    sv = launch.build_serving(launch.build_parser().parse_args(argv))
+    ec = sv.engine.ec
+    assert (ec.max_slots, ec.max_len, ec.track_stats, ec.prefill_chunk) == (
+        2, 11, True, 4)
+    assert sv.arrivals == [0, 2] and [len(r.prompt) for r in sv.requests] \
+        == [5, 9]
+    assert not sv.cfg.kahan_attention
+    cfg = get_smoke("olmo-1b").replace(kahan_attention=True)
+    again = launch.build_serving(launch.build_parser().parse_args(argv),
+                                 cfg=cfg)
+    assert again.cfg is cfg and again.engine.prefill_body == "flash"
+    for x, y in zip(jax.tree.leaves(sv.engine.params),
+                    jax.tree.leaves(again.engine.params)):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+    with pytest.raises(ValueError, match="--prefill-mode"):
+        launch.build_serving(launch.build_parser().parse_args(
+            argv[:-4] + ["--prefill-mode", "fused"]))
+
+
+def test_compile_cache_helper_prefers_env_dir(monkeypatch):
+    """The entry points' cache helper: ``JAX_COMPILATION_CACHE_DIR``
+    wins and nothing else is set; otherwise a fixed ``.jax_cache/`` at
+    the checkout root. ``jax.config.update`` is stubbed, so the cache
+    stays off in the test process."""
+    from repro.launch import compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    root = compile_cache.DEFAULT_DIR.parent
+    assert path == str(root / ".jax_cache")
+    assert (root / "src" / "repro" / "launch" / "compile_cache.py").exists()
+    assert calls == [("jax_compilation_cache_dir", path)]
