@@ -1,0 +1,34 @@
+"""The command refuses to run without a TPU, and prints no result."""
+
+import os
+import pathlib
+import shutil
+import subprocess
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+ARGS = ["--workload", "dot-2e27.kahan", "--seed", str(2**33 + 1),
+        "--seconds", "1", "--trace", "0"]
+
+
+def _run(cwd):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    return subprocess.run([sys.executable, "bench/run.py", *ARGS], cwd=cwd,
+                          env=env, capture_output=True, text=True, timeout=120)
+
+
+def test_exits_nonzero_without_a_tpu():
+    p = _run(ROOT)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "no TPU" in p.stderr
+
+
+def test_exits_nonzero_with_only_the_benchmark(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    p = _run(tmp_path)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
