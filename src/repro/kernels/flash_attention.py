@@ -275,6 +275,7 @@ def flash_accumulators(q, k, v, *, block_q, block_k,
         compute_dtype=compute_dtype, interpret=interpret)
     return pl.pallas_call(
         kernel,
+        name="flash_accumulators",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),
@@ -391,6 +392,7 @@ def flash_chunk_accumulators(q, k, v, q_off, *, block_q, block_k,
         compute_dtype=compute_dtype, interpret=interpret)
     return pl.pallas_call(
         kernel,
+        name="flash_chunk_accumulators",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda b, i, j: (0, 0),

@@ -88,6 +88,7 @@ def dot_accumulators(a: jax.Array, b: jax.Array, *,
                                compute_dtype=compute_dtype)
     s, c = pl.pallas_call(
         kernel,
+        name="dot_accumulators",
         grid=(steps,),
         in_specs=[
             pl.BlockSpec((rows, LANES), lambda g: (g, 0)),
@@ -138,6 +139,7 @@ def dot_accumulators_batched(a: jax.Array, b: jax.Array, *,
                                compute_dtype=compute_dtype, step_dim=1)
     s, c = pl.pallas_call(
         kernel,
+        name="dot_accumulators_batched",
         grid=(batch, steps),
         in_specs=[
             pl.BlockSpec((1, rows, LANES), lambda bi, g: (bi, g, 0)),

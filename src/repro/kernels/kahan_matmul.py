@@ -103,6 +103,7 @@ def matmul_accumulators(a: jax.Array, b: jax.Array, *,
                                k_steps=grid[2], compute_dtype=compute_dtype)
     s, c = pl.pallas_call(
         kernel,
+        name="matmul_accumulators",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
@@ -155,6 +156,7 @@ def matmul_accumulators_batched(a: jax.Array, b: jax.Array, *,
                                step_dim=3)
     s, c = pl.pallas_call(
         kernel,
+        name="matmul_accumulators_batched",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_m, block_k),

@@ -62,6 +62,7 @@ def sum_accumulators(x: jax.Array, *, scheme: CompensationScheme,
                                compute_dtype=compute_dtype)
     s, c = pl.pallas_call(
         kernel,
+        name="sum_accumulators",
         grid=(steps,),
         in_specs=[pl.BlockSpec((rows, LANES), lambda g: (g, 0))],
         out_specs=[
@@ -104,6 +105,7 @@ def sum_accumulators_batched(x: jax.Array, *, scheme: CompensationScheme,
                                compute_dtype=compute_dtype, step_dim=1)
     s, c = pl.pallas_call(
         kernel,
+        name="sum_accumulators_batched",
         grid=(batch, steps),
         in_specs=[pl.BlockSpec((1, rows, LANES), lambda bi, g: (bi, g, 0))],
         out_specs=[

@@ -47,9 +47,25 @@ bitwise-neutral: the dense layout is the oracle and every token and
 telemetry value matches it exactly. With the paged layout the per-step
 log line carries the pool counters (pages in use / free, prefix-hit
 tokens, admission stalls on page exhaustion).
+
+``--profile DIR`` writes a ``jax.profiler`` trace of the whole run under
+``DIR`` (``plugins/profile/<time>/*.xplane.pb``; open it in TensorBoard
+or Perfetto, or read it with ``jax.profiler.ProfileData``), with the
+Python tracer off and host spans at level 1, as the benchmark traces.
+The engine's host spans land on the device's timeline. To follow one
+request, take its id from the per-step log: ``serve.submit`` carries
+``request_id`` and ``prompt_len``; each of its prefill chunks is a
+``serve.prefill`` span with the same ``request_id`` (and ``width``,
+``offset``, ``new_program``) inside a ``serve.step`` whose ``step_num``
+is the log's step number; from the step that logs its first token on,
+it is one of the ``live`` slots of each ``serve.tick.dispatch``, and
+the ``jit_tick`` run that follows that span on the device computed its
+token. The ``serve.record`` span of the step that logs its last token
+(``*``) counts it under ``finished``.
 """
 
 import argparse
+import contextlib
 from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -164,6 +180,10 @@ def build_parser() -> argparse.ArgumentParser:
                          "names fail fast with the menu)")
     ap.add_argument("--unroll", type=int, default=8,
                     help="accumulator-group count of the Pallas kernels")
+    ap.add_argument("--profile", default="", metavar="DIR",
+                    help="write a jax.profiler trace of the run under DIR "
+                         "(the engine's serve.* host spans and the device's "
+                         "programs on one timeline)")
     ap.add_argument("--compute-dtype", default="float32",
                     help="accumulate dtype for the compensated kernels "
                          "(float32 | bfloat16 | float64 — f64 needs x64 "
@@ -255,6 +275,20 @@ def build_serving(args: argparse.Namespace,
     return Serving(cfg, cells, requests, arrivals, engine)
 
 
+def profiled(trace_dir: str):
+    """A ``jax.profiler`` trace into ``trace_dir`` (nothing when empty),
+    with the Python tracer off and host spans at level 1."""
+    if not trace_dir:
+        return contextlib.nullcontext()
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    opts.enable_hlo_proto = False
+    return jax.profiler.trace(trace_dir, profiler_options=opts)
+
+
 def main(argv: Optional[Sequence[str]] = None) -> None:
     args = build_parser().parse_args(argv)
     enable_compile_cache()
@@ -269,24 +303,25 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
               f"(per-position fallback — recurrent state or unsupported "
               f"config)")
     paged = engine.kv_layout == "paged"
-    for t, events in engine.stream(requests, arrivals):
-        chunks = " ".join(f"r{rid}+{w}/{body}"
-                          for rid, w, body in engine.last_chunks)
-        emitted = ", ".join(
-            f"r{e.request_id}:{e.token}{'*' if e.done else ''}"
-            for e in events)
-        pages = ""
-        if paged:
-            st = engine.page_stats()
-            pages = (f" pages={st['pages_in_use']}/{st['num_pages']}"
-                     f" stalls={st['page_stalls']}")
-            if args.prefix_cache:
-                pages += (f" prefix-hit={st['prefix_hit_tokens']}tok"
-                          f" cached={st['prefix_cached_pages']}pg")
-        print(f"# step {t:3d} occupancy={engine.scheduler.occupancy} "
-              f"prefilling={len(engine.scheduler.prefilling)} "
-              f"queued={engine.scheduler.queued}{pages}"
-              f"{'  chunks: ' + chunks if chunks else ''}  {emitted}")
+    with profiled(args.profile):
+        for t, events in engine.stream(requests, arrivals):
+            chunks = " ".join(f"r{rid}+{w}/{body}"
+                              for rid, w, body in engine.last_chunks)
+            emitted = ", ".join(
+                f"r{e.request_id}:{e.token}{'*' if e.done else ''}"
+                for e in events)
+            pages = ""
+            if paged:
+                st = engine.page_stats()
+                pages = (f" pages={st['pages_in_use']}/{st['num_pages']}"
+                         f" stalls={st['page_stalls']}")
+                if args.prefix_cache:
+                    pages += (f" prefix-hit={st['prefix_hit_tokens']}tok"
+                              f" cached={st['prefix_cached_pages']}pg")
+            print(f"# step {t:3d} occupancy={engine.scheduler.occupancy} "
+                  f"prefilling={len(engine.scheduler.prefilling)} "
+                  f"queued={engine.scheduler.queued}{pages}"
+                  f"{'  chunks: ' + chunks if chunks else ''}  {emitted}")
     print(f"# compiled prefill programs (width, runs_setup): "
           f"{list(engine.prefill_programs)} body={engine.prefill_body}")
     if paged:

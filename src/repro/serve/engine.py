@@ -139,6 +139,7 @@ from typing import Any, Deque, Dict, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.kernels import schemes as _schemes
@@ -756,7 +757,17 @@ class InferenceEngine:
 
     # ------------------------------------------------------------ submission
     def submit(self, request: Request) -> RequestHandle:
-        """Queue a request; returns its live handle immediately."""
+        """Queue a request; returns its live handle immediately. Under a
+        profiler session the span ``serve.submit`` carries its
+        ``request_id`` and ``prompt_len``, the id that the request's
+        ``serve.prefill`` spans carry too."""
+        with TraceAnnotation("serve.submit") as span:
+            handle = self._queue(request)
+            span.set_metadata(request_id=handle.request_id,
+                              prompt_len=handle.prompt_len)
+        return handle
+
+    def _queue(self, request: Request) -> RequestHandle:
         rid = request.request_id
         if rid is None:
             rid = self._next_id
@@ -822,24 +833,42 @@ class InferenceEngine:
         request whose last chunk lands emits its first token and joins
         the decode batch), then one decode tick over the running slots.
         Returns the tokens emitted this step, prefill completions first.
+
+        Under a ``jax.profiler`` session the step leaves host spans on
+        the profiler's clock (``serve.step`` with ``step_num``, and
+        ``serve.admit``, ``serve.prefill*``, ``serve.tick.*`` and
+        ``serve.record`` inside it); with no session each costs one
+        check of whether a session is active.
         """
         events: List[TokenEvent] = []
         self.last_chunks = []
-        sch = self.scheduler
+        with StepTraceAnnotation("serve.step", step_num=self.t):
+            admitted, chunks = self._admit_and_prefill(events)
+            running = self.scheduler.running
+            if running:
+                self._tick(running, admitted, chunks, events)
+        self.t += 1
+        return events
 
-        # -- admissions + budgeted chunked prefill ------------------------
+    def _admit_and_prefill(self, events: List[TokenEvent]) -> Tuple[int, int]:
+        """Admissions and budgeted chunked prefill; returns (requests
+        admitted, chunks run)."""
+        sch = self.scheduler
         budget = self.ec.prefill_budget
-        spent = 0
+        admitted = spent = 0
         while True:
-            while sch.can_admit():
-                if self.pages is not None and not self._reserve_pages(
-                        sch.peek()):
-                    # page exhaustion: the head blocks IN THE QUEUE
-                    # (strict FIFO — nothing jumps a starved head) until
-                    # finishing requests release pages
-                    self.page_stalls += 1
-                    break
-                sch.admit_next()
+            if sch.can_admit():
+                with TraceAnnotation("serve.admit"):
+                    while sch.can_admit():
+                        if self.pages is not None and not self._reserve_pages(
+                                sch.peek()):
+                            # page exhaustion: the head blocks IN THE QUEUE
+                            # (strict FIFO — nothing jumps a starved head)
+                            # until finishing requests release pages
+                            self.page_stalls += 1
+                            break
+                        sch.admit_next()
+                        admitted += 1
             if budget is not None and spent >= budget:
                 break
             prefilling = sch.prefilling
@@ -849,11 +878,16 @@ class InferenceEngine:
             slot, h = next(iter(prefilling.items()))
             self._run_chunk(slot, h, events)
             spent += 1
+        return admitted, spent
 
-        # -- decode tick over the running slots ---------------------------
-        running = sch.running
-        if running:
-            b = self.ec.max_slots
+    def _tick(self, running: Dict[int, RequestHandle], admitted: int,
+              chunks: int, events: List[TokenEvent]) -> None:
+        """One decode tick over the running slots. The dispatch span's
+        arguments count the step: slots in the tick (``live``), requests
+        still queued, prefill chunks run and requests admitted before
+        it."""
+        b = self.ec.max_slots
+        with TraceAnnotation("serve.tick.inputs"):
             tokens = np.zeros((b,), np.int32)
             pos = np.zeros((b,), np.int32)
             seeds = np.zeros((b,), np.int32)
@@ -867,7 +901,8 @@ class InferenceEngine:
                 eidx[slot] = h.emitted
                 temps[slot] = h.request.sampling.temperature
                 live[slot] = True
-            extra = ()
+            args = tuple(jnp.asarray(a) for a in
+                         (tokens, pos, seeds, eidx, temps, live))
             if self.pages is not None:
                 tables = np.zeros((b, self.slots.max_pages), np.int32)
                 nres = np.zeros((b,), np.int32)
@@ -875,58 +910,72 @@ class InferenceEngine:
                     lease = self._leases[h.request_id]
                     tables[slot] = lease.table
                     nres[slot] = lease.n_pages
-                extra = (jnp.asarray(tables), jnp.asarray(nres))
+                args += (jnp.asarray(tables), jnp.asarray(nres))
+        with TraceAnnotation("serve.tick.dispatch", live=len(running),
+                             queued=self.scheduler.queued, chunks=chunks,
+                             admitted=admitted):
             new_cache, next_tok, norms = self._fns.tick(
-                self.params, self.slots.cache, jnp.asarray(tokens),
-                jnp.asarray(pos), jnp.asarray(seeds), jnp.asarray(eidx),
-                jnp.asarray(temps), jnp.asarray(live), *extra)
-            self.slots.cache = new_cache
+                self.params, self.slots.cache, *args)
+        self.slots.cache = new_cache
+        with TraceAnnotation("serve.tick.readback"):
             toks = np.asarray(next_tok)
             norms = np.asarray(norms)
+        with TraceAnnotation("serve.record") as span:
+            first = len(events)
             for slot, h in running.items():
                 h.pos += 1
                 self._record(h, int(toks[slot]), norms[slot], events)
-
-        self.t += 1
-        return events
+            span.set_metadata(finished=sum(e.done for e in events[first:]))
 
     def _run_chunk(self, slot: int, h: RequestHandle,
                    events: List[TokenEvent]) -> None:
         """Advance one PREFILLING request by one chunk; on the final
         chunk, record emit 0 and move the request into the decode batch.
+        Its span's ``new_program`` is 1 when this engine had not run the
+        chunk's program before (so the call compiled or loaded it).
         """
         offset = h.prefill_pos
         width, nvalid = _next_chunk(h.prompt_len, offset,
                                     self.ec.prefill_chunk)
-        extra = ()
-        resume = 0
-        if self.pages is not None:
-            lease = self._leases[h.request_id]
-            resume = lease.resume
-            extra = (jnp.asarray(lease.table),
-                     jnp.asarray(lease.n_pages, jnp.int32))
+        lease = (self._leases[h.request_id] if self.pages is not None
+                 else None)
+        resume = lease.resume if lease is not None else 0
         # a prefix-resumed request's FIRST chunk is the one at its resume
         # offset — ``prefill_begin`` (dense, per-slot leaves) must still
         # run for it
         first = offset == resume and self._needs_begin
+        new = (width, first) not in self._used_prefill
         self._used_prefill.add((width, first))
         self.last_chunks.append((h.request_id, width, self.prefill_body))
-        fn = self._fns.prefill(width, first)
-        sp = h.request.sampling
-        new_cache, tok, norm = fn(
-            self.params, self.slots.cache, jnp.asarray(slot, jnp.int32),
-            self._chunk_batch(h.request_id, h.request, offset, width,
-                              nvalid),
-            jnp.asarray(offset, jnp.int32), jnp.asarray(nvalid, jnp.int32),
-            jnp.asarray(h.seed, jnp.int32),
-            jnp.asarray(sp.temperature, jnp.float32), *extra)
-        self.slots.cache = new_cache
-        h.prefill_pos = offset + nvalid
-        if h.prefill_pos == h.prompt_len:
-            self._extras_dev.pop(h.request_id, None)
-            self.scheduler.mark_running(h)
-            h.pos = h.prompt_len
-            self._record(h, int(tok), norm, events)
+        with TraceAnnotation("serve.prefill", request_id=h.request_id,
+                             width=width, offset=offset, new_program=int(new)):
+            extra = ()
+            if lease is not None:
+                extra = (jnp.asarray(lease.table),
+                         jnp.asarray(lease.n_pages, jnp.int32))
+            fn = self._fns.prefill(width, first)
+            sp = h.request.sampling
+            args = (jnp.asarray(slot, jnp.int32),
+                    self._chunk_batch(h.request_id, h.request, offset,
+                                      width, nvalid),
+                    jnp.asarray(offset, jnp.int32),
+                    jnp.asarray(nvalid, jnp.int32),
+                    jnp.asarray(h.seed, jnp.int32),
+                    jnp.asarray(sp.temperature, jnp.float32)) + extra
+            with TraceAnnotation("serve.prefill.dispatch"):
+                new_cache, tok, norm = fn(self.params, self.slots.cache,
+                                          *args)
+            self.slots.cache = new_cache
+            h.prefill_pos = offset + nvalid
+            if h.prefill_pos == h.prompt_len:
+                with TraceAnnotation("serve.prefill.readback"):
+                    tok = int(tok)
+                    if self.ec.track_stats:
+                        norm = np.float32(norm)
+                self._extras_dev.pop(h.request_id, None)
+                self.scheduler.mark_running(h)
+                h.pos = h.prompt_len
+                self._record(h, tok, norm, events)
 
     def _record(self, h: RequestHandle, token: int, norm,
                 events: List[TokenEvent]) -> None:
