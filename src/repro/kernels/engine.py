@@ -176,8 +176,9 @@ class CompensatedReduction:
 
     scheme        registered scheme name, CompensationScheme, or a Policy
                   (None -> the ambient ``schemes.use_policy`` default)
-    unroll        accumulator-group count U; kernel block is (8*U, 128)
-                  (None -> policy)
+    unroll        accumulator-group count U: the (8*U, 128) accumulator
+                  tile and the padding unit; a dot grid step streams T
+                  such tiles, T from the shape (None -> policy)
     interpret     None -> ``resolve_interpret`` (Mosaic only on TPU)
     blocks        matmul (block_m, block_n, block_k) defaults (None -> policy)
     compute_dtype accumulate dtype for every kernel body (None -> policy;

@@ -5,7 +5,8 @@ accumulation step is ``scheme.update`` from the compensation-scheme
 registry, so every registered scheme (naive / kahan / pairwise / dot2 /
 custom) works here with no kernel edits. Used for loss/metric
 accumulation and as the building block of the compensated cross-entropy.
-See kahan_dot.py for the design notes.
+See kahan_dot.py for the design notes; unlike the dot, a grid step here
+still streams a single (8*U, 128) tile.
 """
 
 from __future__ import annotations

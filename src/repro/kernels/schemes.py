@@ -437,7 +437,8 @@ class Policy:
     """Frozen per-call-site configuration for the compensated reductions.
 
     scheme         registered scheme name or a CompensationScheme object
-    unroll         accumulator-group count U; 1-D kernel block is (8*U, 128)
+    unroll         accumulator-group count U; 1-D accumulator tile (and
+                   padding unit) is (8*U, 128)
     blocks         matmul (block_m, block_n, block_k) tile sizes
     interpret      None -> engine.resolve_interpret (Mosaic only on TPU)
     compute_dtype  accumulate dtype for every kernel body and oracle:

@@ -29,6 +29,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # ragged (pad-requiring) size — the block-aligned case is a strict subset
 # (padding becomes a no-op) and is covered by the bf16 test at 4096
 SIZES = [8 * 128 * 3 + 41]
+# ragged, 256 tiles of (16, 128) at unroll 2: the dot kernel's grid steps
+# each fold 128 of them
+MULTI_TILE = 16 * 128 * 255 + 41
 
 
 def _batch(b, n, seed=0, dtype=np.float32):
@@ -39,7 +42,7 @@ def _batch(b, n, seed=0, dtype=np.float32):
 
 # --- batched grid == per-call loop, bitwise ---------------------------------
 
-@pytest.mark.parametrize("n", SIZES)
+@pytest.mark.parametrize("n", SIZES + [MULTI_TILE])
 @pytest.mark.parametrize("scheme", ["naive", "kahan", "dot2"])
 def test_batched_dot_bitwise_matches_loop(n, scheme):
     a, b = _batch(5, n, seed=n)
@@ -71,10 +74,11 @@ def test_batched_bf16_promotion_bitwise():
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_vmap_dispatches_to_batched_grid():
+@pytest.mark.parametrize("n", [8 * 128 * 2 + 9, MULTI_TILE])
+def test_vmap_dispatches_to_batched_grid(n):
     """jax.vmap of the scalar entry points must produce the batched-grid
     result (custom_vmap rule), bitwise-equal to the per-call loop."""
-    a, b = _batch(4, 8 * 128 * 2 + 9, seed=11)
+    a, b = _batch(4, n, seed=11)
     vd = jax.vmap(lambda x, y: ops.dot(x, y, scheme="kahan", unroll=2))(a, b)
     ld = jnp.stack([ops.dot(a[i], b[i], scheme="kahan", unroll=2)
                     for i in range(4)])

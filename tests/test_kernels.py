@@ -6,12 +6,15 @@ compared with a tight tolerance (XLA CPU reassociates within-tile dots
 differently for different shapes).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.costmodel import find_pallas_call
 from repro.core import numerics
-from repro.kernels import ops, ref
+from repro.kernels import kahan_dot, ops, ref
+from repro.kernels.engine import CompensatedReduction
 
 
 SIZES = [8 * 128, 8 * 128 * 4 + 17, 50_000]
@@ -24,8 +27,14 @@ def _data(n, dtype=np.float32, seed=0):
             rng.standard_normal(n).astype(np.float32).astype(dtype))
 
 
-@pytest.mark.parametrize("n", SIZES)
-@pytest.mark.parametrize("scheme", ["naive", "kahan", "dot2"])
+#: lengths that make a grid step fold several accumulator tiles: 512
+#: tiles at unroll 1 (256 per step under a 1 MiB cap), and 257 tiles, a
+#: prime count, which keeps one tile per step at unroll 1
+DOT_SIZES = SIZES + [8 * 128 * 512, 8 * 128 * 257]
+
+
+@pytest.mark.parametrize("n", DOT_SIZES)
+@pytest.mark.parametrize("scheme", ["naive", "kahan", "pairwise", "dot2"])
 @pytest.mark.parametrize("unroll", [1, 4])
 def test_dot_kernel_matches_oracle(n, scheme, unroll):
     a, b = _data(n, seed=n)
@@ -33,6 +42,39 @@ def test_dot_kernel_matches_oracle(n, scheme, unroll):
     want = ref.dot_ref(jnp.asarray(a), jnp.asarray(b), scheme=scheme,
                        rows=8 * unroll)
     assert float(got) == float(want), f"{scheme} unroll={unroll} not bitwise"
+
+
+V5E_SCOPED_VMEM = 16 << 20  # v5e's default scoped VMEM limit
+
+
+@pytest.mark.parametrize("n, unroll", [(1 << 27, 8), (1 << 24, 8),
+                                       (8 * 128 * 512, 1), (8 * 128 * 257, 1),
+                                       (50_000, 4), (1, 8)])
+def test_dot_tiles_per_step(n, unroll):
+    """T, the count of accumulator tiles a grid step streams, is the
+    largest divisor of the tile count within the block cap; the grid
+    covers the engine's padded length exactly, and the double-buffered
+    blocks fit v5e's default scoped VMEM."""
+    tile = 8 * unroll * 128
+    tile_bytes = tile * 4
+    steps = -(-n // tile)
+    t = kahan_dot._tiles_per_step(steps, tile_bytes)
+    assert steps % t == 0
+    assert t == 1 or t * tile_bytes <= kahan_dot._BLOCK_BYTES
+    assert not any(steps % d == 0 and d * tile_bytes <= kahan_dot._BLOCK_BYTES
+                   for d in range(t + 1, steps + 1))
+    # operands and outputs double-buffered, plus the (s, c) scratch
+    assert 2 * 2 * t * tile_bytes + 6 * tile_bytes < V5E_SCOPED_VMEM
+
+    x = jax.ShapeDtypeStruct((n,), jnp.float32)
+    call = find_pallas_call(jax.make_jaxpr(
+        lambda a, b: ops.dot(a, b, scheme="kahan", unroll=unroll))(x, x))
+    (grid,) = call.params["grid_mapping"].grid
+    padded = CompensatedReduction(unroll=unroll).block * steps
+    assert grid * t == steps
+    assert [v.aval.size for v in call.invars] == [padded, padded]
+    if n == 1 << 27:
+        assert grid <= 1024
 
 
 @pytest.mark.parametrize("n", SIZES)

@@ -29,6 +29,7 @@ from repro.models import build_model
 from repro.serve import EngineConfig, InferenceEngine
 
 N_VEC = 1 << 24                    # 64 MiB per f32 operand: HBM-resident
+BATCH, WIDTH = 4, 1 << 20          # batched dot: 4 grid steps of 32 tiles a row
 MM = 2048                          # matmul M = N = K
 BH, S, DH = 16, 2048, 128          # flash: olmo-1b heads x context x dh
 
@@ -70,6 +71,9 @@ def test_dot_and_asum_compile_for_v5e(one_chip, name):
     asum = functools.partial(ops.asum, scheme=name, interpret=False)
     _assert_mosaic(_compile(dot, x, x), f"dot/{name}")
     _assert_mosaic(_compile(asum, x), f"asum/{name}")
+    rows = _sds(one_chip, (BATCH, WIDTH))
+    batched = functools.partial(ops.batched_dot, scheme=name, interpret=False)
+    _assert_mosaic(_compile(batched, rows, rows), f"batched_dot/{name}")
 
 
 def test_matmul_compiles_for_v5e(one_chip):
