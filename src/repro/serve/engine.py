@@ -122,7 +122,7 @@ the zero-fill gather / zero-reset-on-free pristine-bits guarantee.
 Page reservation is whole-request at admission (never in a trace,
 never mid-decode; exhaustion blocks admission FIFO — the ALLOCATING
 state), and ``EngineConfig.prefix_cache`` adds a refcounted radix tree
-(``repro.serve.prefix``) over finished prompts so shared prefixes
+(``repro.serve.prefix``) over prefilled prompts so shared prefixes
 admit by reference and resume prefill at the shared page boundary.
 Recurrent-only families (SSM/xLSTM, all-window hybrids) have no
 pageable leaf and fall back to dense; ``engine.kv_layout`` reports the
@@ -230,11 +230,11 @@ class EngineConfig:
                    (max_slots * max_len / page_size). Admission blocks
                    (deterministic FIFO) when the pool runs short;
                    requests that could never fit fail fast at submit
-    prefix_cache   keep finished requests' full prompt pages in a
-                   refcounted radix tree (``repro.serve.prefix``) so a
-                   request with a resident prompt prefix admits by
-                   reference and resumes prefill at the shared offset.
-                   Paged layout only
+    prefix_cache   keep requests' full prompt pages, from the end of
+                   their prefill, in a refcounted radix tree
+                   (``repro.serve.prefix``) so a request with a resident
+                   prompt prefix admits by reference and resumes
+                   prefill at the shared offset. Paged layout only
     """
 
     max_slots: int = 4
@@ -674,9 +674,11 @@ class _PageLease:
              then the request's own pages, NULL (0) beyond ``n_pages``
     n_pages  reserved pages total (every page the request can touch —
              fixed at admission, so decode never allocates)
-    shared   acquired prefix-tree path (refs held until finish)
-    own      engine-owned pages (freed — or adopted by the prefix tree —
-             at finish)
+    shared   prefix-tree nodes the request holds a reference on until
+             it finishes: its matched path, then the nodes that adopt
+             its own prompt pages when its prefill completes
+    own      engine-owned pages, zero-reset and freed at finish (pages
+             the prefix tree adopts leave this list)
     resume   prefill resume offset: positions [0, resume) came in by
              reference (+ at most one copy-on-write page) and are never
              re-prefilled
@@ -975,6 +977,8 @@ class InferenceEngine:
                 self._extras_dev.pop(h.request_id, None)
                 self.scheduler.mark_running(h)
                 h.pos = h.prompt_len
+                if lease is not None:
+                    self._donate_prompt(h, lease)
                 self._record(h, tok, norm, events)
 
     def _record(self, h: RequestHandle, token: int, norm,
@@ -1032,7 +1036,28 @@ class InferenceEngine:
         runs EXACTLY the chunk programs its private prefill would have
         run from that offset (cross-width flash equality is allclose,
         not bitwise; alignment keeps shared-vs-private bitwise).
+
+        Under a profiler session the span ``serve.reserve`` (inside
+        ``serve.admit``) carries ``request_id``, ``prompt_len``,
+        ``hit_tokens`` (the resume offset), ``shared_pages``,
+        ``new_pages``, ``evicted_pages`` and ``admitted`` (0 when the
+        head stalls): over the admitted spans ``hit_tokens`` sums to
+        ``prefix_hit_tokens``, and the stalls count ``page_stalls``.
         """
+        with TraceAnnotation("serve.reserve") as span:
+            lease, evicted = self._lease(h)
+            ok = lease is not None
+            span.set_metadata(
+                request_id=h.request_id, prompt_len=h.prompt_len,
+                hit_tokens=lease.resume if ok else 0,
+                shared_pages=len(lease.shared) if ok else 0,
+                new_pages=len(lease.own) if ok else 0,
+                evicted_pages=evicted, admitted=int(ok))
+        return ok
+
+    def _lease(self, h: RequestHandle) -> Tuple[Optional[_PageLease], int]:
+        """``_reserve_pages``' work: (the lease, or None when the pool
+        is short, and the cached prefix pages evicted for it)."""
         ec = self.ec
         ps = ec.page_size
         h.status = ALLOCATING
@@ -1056,6 +1081,7 @@ class InferenceEngine:
             resume = r
         shared = len(path)
         need = total - shared
+        freed: List[int] = []
         if self.prefix is not None:
             self.prefix.acquire(path)
             if self.pages.free_count < need:
@@ -1069,7 +1095,7 @@ class InferenceEngine:
             if self.prefix is not None:
                 self.prefix.release(path)
             h.status = QUEUED
-            return False
+            return None, len(freed)
         own = self.pages.alloc(need)
         if sharable and self.prefill_body == "scan":
             # copy-on-write at the first divergent page (scan body only —
@@ -1086,46 +1112,58 @@ class InferenceEngine:
         for j, node in enumerate(path):
             table[j] = node.page
         table[shared:shared + need] = own
-        self._leases[h.request_id] = _PageLease(
-            table=table, n_pages=total, shared=path, own=own, resume=resume)
+        lease = _PageLease(table=table, n_pages=total, shared=path,
+                           own=own, resume=resume)
+        self._leases[h.request_id] = lease
         h.prefill_pos = resume
         self.prefix_hit_tokens += resume
-        return True
+        return lease, len(freed)
+
+    def _donate_prompt(self, h: RequestHandle, lease: _PageLease) -> None:
+        """Prefill-completion hook (prefix cache on): offer the request's
+        full prompt pages to the prefix tree as soon as no later write
+        can touch them — decode writes only at positions >= prompt_len —
+        so requests admitted while the donor still decodes share them.
+        First insert of a page run wins (the bitwise contract makes any
+        two requests' bits for identical full-page prompt runs
+        identical, so which donor wins is unobservable). The request
+        holds a reference on every node that adopted one of its pages
+        until it finishes, so eviction cannot reclaim a page it reads."""
+        if self.prefix is None or not self._sharable(h):
+            return
+        ps = self.ec.page_size
+        if self.prefill_body == "flash":
+            # only positions computed in FULL chunk-width programs are
+            # donor-eligible under flash (tail buckets are width-
+            # dependent): insert pages fully inside that region
+            c = self.ec.prefill_chunk
+            n_ins = (c * ((h.prompt_len - 1) // c)) // ps
+        else:
+            n_ins = h.prompt_len // ps
+        if not n_ins:
+            return
+        prompt = [int(t) for t in np.asarray(h.request.prompt)]
+        adopted, _ = self.prefix.insert(prompt, n_ins, lease.table[:n_ins])
+        if adopted:
+            taken = set(adopted)
+            nodes = [n for n in self.prefix.match(prompt)[:n_ins]
+                     if n.page in taken]
+            self.prefix.acquire(nodes)
+            lease.shared = lease.shared + nodes
+            lease.own = [p for p in lease.own if p not in taken]
 
     def _release_pages(self, h: RequestHandle) -> None:
         """Finish hook (runs right after the slot is released): drop the
-        request's prefix references, offer its full prompt pages to the
-        prefix tree (first insert of a page run wins — the bitwise
-        contract makes any two requests' bits for identical full-page
-        prompt runs identical, so which donor wins is unobservable), and
-        zero-reset + free whatever the tree did not adopt. The leak
-        invariant: after a drained trace, free pages + tree-owned pages
-        == num_pages."""
+        request's prefix references (its matched path and the nodes that
+        adopted its prompt pages) and zero-reset + free its own pages.
+        The leak invariant: after a drained trace, free pages +
+        tree-owned pages == num_pages."""
         lease = self._leases.pop(h.request_id)
-        own = list(lease.own)
         if self.prefix is not None:
             self.prefix.release(lease.shared)
-            if self._sharable(h):
-                ps = self.ec.page_size
-                if self.prefill_body == "flash":
-                    # only positions computed in FULL chunk-width
-                    # programs are donor-eligible under flash (tail
-                    # buckets are width-dependent): insert pages fully
-                    # inside that region
-                    c = self.ec.prefill_chunk
-                    n_ins = (c * ((h.prompt_len - 1) // c)) // ps
-                else:
-                    n_ins = h.prompt_len // ps
-                if n_ins:
-                    prompt = [int(t) for t in np.asarray(h.request.prompt)]
-                    adopted, _ = self.prefix.insert(
-                        prompt, n_ins, lease.table[:n_ins])
-                    if adopted:
-                        taken = set(adopted)
-                        own = [p for p in own if p not in taken]
-        if own:
-            self.slots.reset_pages(own)   # pristine before the free list
-            self.pages.free(own)
+        if lease.own:
+            self.slots.reset_pages(lease.own)  # pristine before reuse
+            self.pages.free(lease.own)
 
     @property
     def kv_layout(self) -> str:

@@ -276,13 +276,20 @@ class PagedKVCache:
                     "it (keep the leaf dense via the kv_ring spec flag)")
 
         # Dense leaves keep their full max_slots allocation; pageable
-        # leaves are replaced by pristine-zero pools (the transient
-        # full-size arrays are dropped right here, before first use).
-        full, _ = model.init_cache(max_slots, max_len)
+        # leaves are replaced by pristine-zero pools. Where every leaf
+        # is pageable only the shapes of the full cache are taken, so
+        # set-up never holds a dense-size cache beside the pool (where
+        # some are dense, the transient full-size arrays are dropped
+        # right here, before first use).
+        flat_s = jax.tree.leaves(self.page_axes)
+        if all(s >= 0 for s in flat_s):
+            full = jax.eval_shape(
+                lambda: model.init_cache(max_slots, max_len)[0])
+        else:
+            full, _ = model.init_cache(max_slots, max_len)
         flat_full = jax.tree.leaves(full)
         flat_row = jax.tree.leaves(row)
         flat_b = jax.tree.leaves(self.batch_axes)
-        flat_s = jax.tree.leaves(self.page_axes)
         flat = []
         for lf, lr, b, s in zip(flat_full, flat_row, flat_b, flat_s):
             if s < 0:

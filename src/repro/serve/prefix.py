@@ -125,7 +125,7 @@ class RadixPrefixTree:
     # ------------------------------------------------------------ insertion
     def insert(self, prompt: Sequence[int], n_pages: int,
                pages: Sequence[int]) -> Tuple[List[int], List[int]]:
-        """Register a finished request's first ``n_pages`` prompt pages.
+        """Register a prefilled request's first ``n_pages`` prompt pages.
 
         ``pages[j]`` is the request's pool page for logical page ``j``.
         Walks existing nodes (their pages already hold the identical

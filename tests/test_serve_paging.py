@@ -215,6 +215,41 @@ def test_copy_on_write_partial_page(tiny_model):
         first_donor[0].telemetry)
 
 
+def test_prompt_pages_shared_while_the_donor_decodes(tiny_model):
+    """A prompt's full pages enter the prefix tree when its prefill
+    completes: a request arriving while the donor still decodes admits
+    by reference, emits its private bits, and the donor's pages stay
+    resident (referenced) until both finish."""
+    cfg, model, params = tiny_model
+    rng = np.random.default_rng(67)
+    base = rng.integers(0, cfg.vocab_size, (9,)).astype(np.int32)
+
+    def mk(tail, rid, n):
+        return Request(prompt=np.concatenate([base, tail]).astype(np.int32),
+                       sampling=SamplingParams(temperature=0.5,
+                                               max_new_tokens=n, seed=rid),
+                       request_id=rid)
+
+    donor = mk(rng.integers(0, cfg.vocab_size, (3,)), 0, 10)
+    benef = mk(rng.integers(0, cfg.vocab_size, (2,)), 1, 3)
+    priv, _ = _run(cfg, _paged(max_len=32), model, params, [donor, benef],
+                   [0, 2])
+    eng = InferenceEngine(cfg, _paged(max_len=32, prefix_cache=True),
+                          model=model, params=params)
+    served = {}
+    for t, _events in eng.stream([donor, benef], [0, 2], _sink=served):
+        if t == 2:
+            # admitted at step 2: 2 shared pages, then 1 token copied on
+            # write from the donor's third page
+            assert not served[0].done and eng.prefix_hit_tokens == 9
+            assert eng.prefix.referenced_pages == 3
+    assert {r: (tuple(h.tokens), tuple(h.telemetry))
+            for r, h in served.items()} == priv
+    st = eng.page_stats()
+    assert eng.prefix.referenced_pages == 0
+    assert st["free_pages"] + st["prefix_pages"] == eng.num_pages
+
+
 def test_prefix_hit_full_prompt_resumes_at_last_position(tiny_model):
     """A fully-resident prompt still re-prefills at least one position
     (the final chunk's logits emit token 0) — and emits the same bits
@@ -252,6 +287,74 @@ def test_prefix_eviction_reclaims_cached_pages(tiny_model):
     st = eng.page_stats()
     assert st["free_pages"] + st["prefix_pages"] == eng.num_pages
     assert st["prefix_pages"] == 3           # req 1's pages replaced req 0's
+
+
+# ---------------------------------------------------------------------------
+# Paged + flash chunk body + prefix cache against dense + flash
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tiny_flash():
+    """The tiny model with ``kahan_attention``: flash prefill chunks run
+    the engine's chunk flash kernel, as the served olmo-1b does."""
+    cfg = _tiny_cfg(kahan_attention=True)
+    model = build_model(cfg)
+    params, _ = model.init(jax.random.key(0))
+    return cfg, model, params
+
+
+def _sessions(cfg, head_len, tails, seed):
+    """Requests that open with one shared ``head_len``-token system
+    prompt, each followed by its own part of ``tails[i]`` tokens."""
+    rng = np.random.default_rng(seed)
+    head = rng.integers(0, cfg.vocab_size, (head_len,))
+    return [Request(prompt=np.concatenate([
+        head, rng.integers(0, cfg.vocab_size, (n,))]).astype(np.int32),
+        sampling=SamplingParams(temperature=0.5, max_new_tokens=3,
+                                seed=i),
+        request_id=i) for i, n in enumerate(tails)]
+
+
+def _flash(**kw):
+    return _ec(max_len=48, prefill_chunk=8, prefill_mode="flash", **kw)
+
+
+# the first request finishes (and donates its prompt pages) before the
+# rest arrive; the last two arrive together and share the tree's pages
+ARRIVE = [0, 6, 9, 9]
+
+
+@pytest.mark.parametrize("head_len, tails, resume", [
+    (24, [5, 9, 3, 12], 24),      # 3 whole chunks of 8: resume at 24
+    (21, [6, 11, 2, 14], 16),     # 5 pages of 4, 2 chunks of 8 plus 5
+], ids=["aligned", "unaligned"])
+@pytest.mark.parametrize("scheme", ["naive", "kahan", "pairwise", "dot2"])
+def test_paged_flash_prefix_vs_dense_flash_bitwise(tiny_flash, scheme,
+                                                   head_len, tails, resume):
+    """The served configuration's path: paged KV, the flash chunk body
+    and the prefix cache give the tokens and telemetry of dense + flash
+    bit for bit, for every scheme, while the later requests resume from
+    the donor's pages at the offset both the page and the chunk grid
+    hold (an unaligned system prompt's hit is floored to it)."""
+    cfg, model, params = tiny_flash
+    pol = Policy(scheme=scheme, unroll=2)
+    reqs = _sessions(cfg, head_len, tails, seed=len(scheme) + head_len + 46)
+    dense, _ = _run(cfg, _flash(policy=pol), model, params, reqs, ARRIVE)
+    eng = InferenceEngine(cfg, _flash(policy=pol, kv_layout="paged",
+                                      page_size=4, prefix_cache=True),
+                          model=model, params=params)
+    assert eng.kv_layout == "paged" and eng.prefill_body == "flash"
+    resumes, served = {}, {}
+    for _t, _e in eng.stream(reqs, ARRIVE, _sink=served):
+        resumes.update({rid: lease.resume
+                        for rid, lease in eng._leases.items()})
+    assert resumes == {0: 0, 1: resume, 2: resume, 3: resume}
+    assert eng.prefix_hit_tokens == 3 * resume
+    paged = {r: (tuple(h.tokens), tuple(h.telemetry))
+             for r, h in served.items()}
+    assert dense == paged, f"{scheme}: paged+flash+prefix diverges"
+    st = eng.page_stats()
+    assert st["free_pages"] + st["prefix_pages"] == eng.num_pages
 
 
 # ---------------------------------------------------------------------------

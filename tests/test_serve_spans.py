@@ -108,12 +108,17 @@ def traced(tiny, tmp_path_factory, request):
     d = tmp_path_factory.mktemp(f"trace_{request.param}")
     with jax.profiler.trace(str(d)):
         served, steps = _serve(tiny, request.param)
-    return plain, served, steps, _host_spans(d)
+    return plain, served, steps, _host_spans(d), request.param
 
 
 def test_spans_are_named_and_nest_in_the_step(traced):
-    _, _, steps, spans = traced
-    assert {s[0] for s in spans} == NAMES
+    _, _, steps, spans, kv_layout = traced
+    # page reservation exists on the paged path alone
+    want = NAMES | {"serve.reserve"} if kv_layout == "paged" else NAMES
+    assert {s[0] for s in spans} == want
+    admits = [s for s in spans if s[0] == "serve.admit"]
+    for _, a, b, _ in (s for s in spans if s[0] == "serve.reserve"):
+        assert any(sa <= a and b <= sb for _, sa, sb, _ in admits)
     step_spans = [s for s in spans if s[0] == "serve.step"]
     assert [s[3]["step_num"] for s in step_spans] == list(range(len(steps)))
     for name, a, b, _ in spans:
@@ -136,7 +141,7 @@ def _per_step(spans, name):
 
 
 def test_counters_match_what_the_engine_did(traced):
-    _, _, steps, spans = traced
+    _, _, steps, spans, _ = traced
     dispatch = _per_step(spans, "serve.tick.dispatch")
     record = _per_step(spans, "serve.record")
     prefill = _per_step(spans, "serve.prefill")
@@ -155,7 +160,7 @@ def test_counters_match_what_the_engine_did(traced):
 
 
 def test_request_ids_follow_a_request(traced):
-    _, served, steps, spans = traced
+    _, served, steps, spans, _ = traced
     submits = [s[3] for s in spans if s[0] == "serve.submit"]
     assert sorted(a["request_id"] for a in submits) == sorted(served)
     lens = {a["request_id"]: a["prompt_len"] for a in submits}
@@ -176,7 +181,7 @@ def test_request_ids_follow_a_request(traced):
 
 
 def test_profiling_changes_no_bit(traced):
-    plain, served, _, _ = traced
+    plain, served, _, _, _ = traced
     assert served == plain
 
 
@@ -195,3 +200,49 @@ def test_launcher_profile_writes_the_spans(tmp_path, monkeypatch):
         == {0, 1}
     assert [s[3]["request_id"] for s in spans if s[0] == "serve.prefill"] \
         == [0, 0, 1]
+
+
+def _serve_sessions(tiny):
+    """A paged engine with the prefix cache and a pool too small for
+    every request at once: a shared 16-token head admits by reference,
+    and the queue's head stalls for pages."""
+    cfg, model, params = tiny
+    ec = EngineConfig(**{**EC.__dict__, "kv_layout": "paged",
+                         "page_size": 4, "num_pages": 8,
+                         "prefix_cache": True, "prefill_budget": None})
+    eng = InferenceEngine(cfg, ec, model=model, params=params)
+    rng = np.random.default_rng(11)
+    head = rng.integers(0, cfg.vocab_size, (16,))
+    # the last request shares nothing: it takes the head's cached pages
+    heads = [head] * 4 + [rng.integers(0, cfg.vocab_size, (16,))]
+    reqs = [Request(prompt=np.concatenate(
+        [h, rng.integers(0, cfg.vocab_size, (n,))]).astype(np.int32),
+        sampling=SamplingParams(temperature=0.5, max_new_tokens=4, seed=i),
+        request_id=i) for i, (h, n) in enumerate(zip(heads, (3, 7, 2, 9, 8)))]
+    served = eng.run(reqs, [0, 3, 3, 3, 4])
+    return eng, {rid: (tuple(h.tokens), tuple(h.telemetry))
+                 for rid, h in served.items()}
+
+
+def test_reserve_spans_agree_with_the_counters(tiny, tmp_path):
+    """``serve.reserve``: one span per attempt to admit the queue's head;
+    the admitted spans' ``hit_tokens`` sum to ``prefix_hit_tokens`` and
+    the stalled ones count ``page_stalls``; pages add up, and profiling
+    changes no bit on the paged path."""
+    _, plain = _serve_sessions(tiny)
+    with jax.profiler.trace(str(tmp_path)):
+        eng, served = _serve_sessions(tiny)
+    assert served == plain
+    args = [s[3] for s in _host_spans(tmp_path) if s[0] == "serve.reserve"]
+    admitted = [a for a in args if a["admitted"] == 1]
+    stalled = [a for a in args if a["admitted"] == 0]
+    assert sorted(a["request_id"] for a in admitted) == list(range(5))
+    assert sum(a["hit_tokens"] for a in admitted) == eng.prefix_hit_tokens > 0
+    assert len(stalled) == eng.page_stalls > 0
+    assert any(a["evicted_pages"] > 0 for a in args)
+    for a in admitted:
+        need = -(-(a["prompt_len"] + 4 - 1) // 4)
+        assert a["shared_pages"] + a["new_pages"] == need
+        assert a["hit_tokens"] <= 4 * a["shared_pages"] + 3
+    for a in stalled:
+        assert a["hit_tokens"] == a["shared_pages"] == a["new_pages"] == 0

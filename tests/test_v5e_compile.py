@@ -137,3 +137,25 @@ def test_olmo_1b_decode_tick_compiles_for_v5e(one_chip):
     shapes = jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), args)
     text = tick.lower(*shapes).compile().as_text()
     _assert_mosaic(text, "olmo-1b decode tick")
+
+
+def test_olmo_1b_paged_tick_and_flash_chunk_compile_for_v5e(one_chip):
+    """The paged decode tick and one paged flash-chunk prefill width of
+    olmo-1b at full widths, as ``olmo-1b-paged`` serves them: each slot's
+    row gathered through its page table, ``kahan_attention`` in the
+    chunk, the prefix cache on."""
+    cfg = get_config("olmo-1b").replace(kahan_attention=True)
+    model = build_model(cfg)
+    params = jax.eval_shape(lambda k: model.init(k)[0], jax.random.key(0))
+    engine = InferenceEngine(
+        cfg, EngineConfig(max_slots=4, max_len=256, track_stats=True,
+                          prefill_mode="flash", kv_layout="paged",
+                          page_size=16, prefix_cache=True,
+                          policy=Policy(scheme="kahan", interpret=False)),
+        model=model, params=params)
+    assert engine.kv_layout == "paged" and engine.prefill_body == "flash"
+    for what, (fn, args) in (("paged tick", engine.trace_tick()),
+                             ("paged flash chunk", engine.trace_prefill(64))):
+        shapes = jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), args)
+        _assert_mosaic(fn.lower(*shapes).compile().as_text(),
+                       f"olmo-1b {what}")
