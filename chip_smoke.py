@@ -16,12 +16,19 @@ than naive's.
 
 Serving phase: olmo-1b at its published widths with random weights from
 ``--seed``, built by the serving launcher's own ``build_serving``. The
-same staggered four-request trace is served twice, with the scan prefill
-body and with ``kahan_attention=True, prefill_mode="flash"``. Every
-request must finish with its token count and finite telemetry, one
-request replayed solo must give bitwise the tokens and telemetry of the
-interleaved run, and the two bodies' prefill logits at each request's
-last prompt position must agree within ``LOGITS_TOL``.
+same staggered four-request trace is served three times on 4 slots: with
+the scan prefill body, with ``kahan_attention=True, prefill_mode="flash"``,
+and with flash prefill from the paged KV pool. A 16-request trace is then
+served with flash prefill on 16 slots, the serving cells' width, in both
+layouts. Every request must finish with its token count and finite
+telemetry. In each run one request, which decoded in another slot with
+every slot busy beside it, is replayed solo in slot 0 and must give
+bitwise the tokens and telemetry of the interleaved run: the batched
+decode tick's rows do not depend on their slot or their neighbours.
+Every request served from the paged pool must give bitwise the tokens
+and telemetry of the dense flash run at the same width. The two bodies'
+prefill logits at each request's last prompt position must agree within
+``LOGITS_TOL``.
 
 ``--chips 4``: ``collectives.sharded_{asum,dot,matmul}`` on a 4-device
 mesh. Each result must equal, bitwise, ``merge_accumulators`` of the same
@@ -60,6 +67,15 @@ GENDOT = (1 << 20, 1e2)
 # prompt lengths need only the 64-wide chunk and its 32-wide tail bucket.
 TRACE = "0:17:32,1:96:32,2:31:32,4:192:32"
 SOLO = 3                        # request replayed alone (the longest)
+# the serving cells' width: 16 requests at once, one prefill chunk each,
+# so request SOLO16 decodes in slot SOLO16 with every slot busy beside it.
+# Their longest row is 80 positions, a whole number of 16-position pages,
+# so the dense and the paged engine fit the same max_len.
+SLOTS16 = 16
+SOLO16 = 11
+TRACE16 = ",".join(f"0:{17 + 3 * i}:{24 if i == SOLO16 else 18}"
+                   + (":0.7" if i == SOLO16 else "")
+                   for i in range(SLOTS16))
 
 # flash vs the f64 host reference: largest absolute error on outputs of
 # magnitude O(1) (an average of unit normals)
@@ -276,59 +292,116 @@ def last_prompt_logits(serving, body: str, chunk: int = 64):
     return out
 
 
-def serve_once(seed: int, mode: str, cfg):
-    """Build through the launcher, serve the trace, check it; returns
-    (serving, handles by request id)."""
+def serve_once(seed: int, mode: str, cfg, layout: str = "dense",
+               trace: str = TRACE, slots: int = 4, solo: int = SOLO):
+    """Build through the launcher, serve ``trace`` on ``slots`` slots,
+    check it; returns (serving, handles by request id). Request ``solo``
+    is then replayed alone: it must land in another slot than it had
+    interleaved, where every slot was busy beside it, and give the same
+    bits."""
     from repro.launch import serve
     from repro.serve import InferenceEngine
 
     args = serve.build_parser().parse_args(
-        ["--arch", cfg.name, "--trace", TRACE, "--max-slots", "4",
-         "--stats", "--seed", str(seed), "--prefill-mode", mode])
+        ["--arch", cfg.name, "--trace", trace, "--max-slots", str(slots),
+         "--stats", "--seed", str(seed), "--prefill-mode", mode,
+         "--kv-layout", layout])
     sv = serve.build_serving(args, cfg=cfg)
     eng = sv.engine
-    check(eng.prefill_body == mode,
-          f"{mode}: engine runs the {eng.prefill_body!r} prefill body")
+    check(eng.prefill_body == mode and eng.kv_layout == layout,
+          f"{mode}/{layout}: engine runs the {eng.prefill_body!r} prefill "
+          f"body on the {eng.kv_layout!r} layout")
+    req = sv.requests[solo]
+    served = {}
     t0 = time.perf_counter()
-    served = eng.run(sv.requests, sv.arrivals)
+    inter_slot, occupancy = run_watching(eng, sv.requests, sv.arrivals,
+                                         req.request_id, served)
     cold = time.perf_counter() - t0
-    for req in sv.requests:
-        h = served[req.request_id]
-        want = req.sampling.max_new_tokens
+    for r in sv.requests:
+        h = served[r.request_id]
+        want = r.sampling.max_new_tokens
         check(h.done and len(h.tokens) == want,
-              f"{mode}: request {req.request_id} emitted {len(h.tokens)} "
-              f"of {want} tokens")
+              f"{mode}/{layout}: request {r.request_id} emitted "
+              f"{len(h.tokens)} of {want} tokens")
         check(len(h.telemetry) == want
               and bool(np.all(np.isfinite(h.telemetry))),
-              f"{mode}: request {req.request_id} telemetry is not finite")
-    log(f"serve {cfg.name} prefill={eng.prefill_body} steps={eng.t} "
-        f"programs={list(eng.prefill_programs)} "
+              f"{mode}/{layout}: request {r.request_id} telemetry is not "
+              f"finite")
+    log(f"serve {cfg.name} prefill={eng.prefill_body} layout={layout} "
+        f"slots={slots} steps={eng.t} programs={list(eng.prefill_programs)} "
         f"cold_s(compile+run)={cold:.3f}")
 
-    req = sv.requests[SOLO]
     solo_eng = InferenceEngine(sv.cfg, eng.ec, model=eng.model,
                                params=eng.params)
+    solo_served = {}
     t0 = time.perf_counter()
-    solo = solo_eng.run([req])[req.request_id]
+    solo_slot, _ = run_watching(solo_eng, [req], None, req.request_id,
+                                solo_served)
     warm = time.perf_counter() - t0
-    inter = served[req.request_id]
+    solo, inter = solo_served[req.request_id], served[req.request_id]
     same = (solo.tokens == inter.tokens
             and np.array_equal(np.asarray(solo.telemetry, np.float32),
                                np.asarray(inter.telemetry, np.float32)))
-    log(f"serve {mode} solo-vs-interleaved request={req.request_id} "
-        f"bitwise={same} solo_s={warm:.3f}")
-    check(same, f"{mode}: request {req.request_id} solo differs from its "
-                f"interleaved run")
+    log(f"serve {mode}/{layout} slots={slots} solo-vs-interleaved request="
+        f"{req.request_id} slot solo={solo_slot} interleaved={inter_slot} "
+        f"occupancy_beside_it={sorted(occupancy)} bitwise={same} "
+        f"solo_s={warm:.3f}")
+    check(solo_slot != inter_slot and max(occupancy) == eng.ec.max_slots,
+          f"{mode}/{layout}: the trace did not place request "
+          f"{req.request_id} in another slot at full occupancy")
+    check(same, f"{mode}/{layout}: request {req.request_id} solo differs "
+                f"from its interleaved run")
     return sv, served
+
+
+def run_watching(eng, requests, arrivals, rid: int, sink):
+    """Drive ``requests`` to completion, collecting handles in ``sink``;
+    returns (the slot request ``rid`` decoded in, the set of decode
+    occupancies seen while it ran)."""
+    slot, occupancy = None, set()
+    for _ in eng.stream(requests, arrivals, _sink=sink):
+        running = eng.scheduler.running
+        for s, h in running.items():
+            if h.request_id == rid:
+                slot = s
+                occupancy.add(len(running))
+    return slot, occupancy
+
+
+def check_paged_vs_dense(requests, paged_served, dense_served,
+                         slots: int) -> None:
+    """Every request's tokens and telemetry, served from the paged pool,
+    are bitwise those of the dense run."""
+    for req in requests:
+        rid = req.request_id
+        paged, dense = paged_served[rid], dense_served[rid]
+        same = (paged.tokens == dense.tokens
+                and np.array_equal(np.asarray(paged.telemetry, np.float32),
+                                   np.asarray(dense.telemetry, np.float32)))
+        log(f"serve paged-vs-dense (flash) slots={slots} request={rid} "
+            f"bitwise={same}")
+        check(same, f"slots={slots} request {rid}: the paged layout's "
+                    f"tokens or telemetry differ from the dense layout's")
 
 
 def serving_phase(seed: int) -> None:
     from repro.configs import get_config
 
     cfg = get_config("olmo-1b")
+    kcfg = cfg.replace(kahan_attention=True)
     scan_sv, scan_served = serve_once(seed, "scan", cfg)
-    flash_sv, flash_served = serve_once(
-        seed, "flash", cfg.replace(kahan_attention=True))
+    flash_sv, flash_served = serve_once(seed, "flash", kcfg)
+    paged_sv, paged_served = serve_once(seed, "flash", kcfg, layout="paged")
+    check_paged_vs_dense(paged_sv.requests, paged_served, flash_served, 4)
+
+    # the serving cells' width: the replayed request decoded beside 15
+    served16 = {}
+    for layout in ("dense", "paged"):
+        sv16, served16[layout] = serve_once(
+            seed, "flash", kcfg, layout=layout, trace=TRACE16,
+            slots=SLOTS16, solo=SOLO16)
+    check_paged_vs_dense(sv16.requests, served16["paged"], served16["dense"],
+                         SLOTS16)
 
     t0 = time.perf_counter()
     scan_logits = last_prompt_logits(scan_sv, "scan")

@@ -27,7 +27,7 @@
 # Stage 0b re-audits the contract at the IR level: the registered entry
 # points (repro.analysis.targets) are traced to jaxprs/HLO and checked
 # for what source text cannot prove (no psum primitive however spelled,
-# barriers surviving lowering, the decode tick compiling to a slot scan,
+# barriers surviving lowering, the decode tick as one pinned batched body,
 # the O(#buckets) prefill program bound). Budget: < 60 s.
 # Stage 0c audits the PERFORMANCE contract: every registered scheme's
 # kernel bodies are traced at audit shapes, their instruction mix and

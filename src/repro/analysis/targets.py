@@ -51,8 +51,8 @@ class TraceArtifact:
     body_jaxpr        the standalone shared-block-body trace
                       (trace-barrier-pinned containment reference)
     compute_dtype     resolved accumulate dtype (trace-accum-dtype)
-    slot_scan_length  expected decode-scan trip count
-                      (trace-decode-is-scan)
+    slot_width        the decode tick's fixed slot width
+                      (trace-decode-is-batched)
     hlo               lazy () -> (lowered_hlo_text, optimized_hlo_text)
                       (trace-barrier-survives-fusion)
     program_keys      prefill (width, runs_begin) family
@@ -64,7 +64,7 @@ class TraceArtifact:
     oracle_jaxpr: Any = None
     body_jaxpr: Any = None
     compute_dtype: Any = None
-    slot_scan_length: Optional[int] = None
+    slot_width: Optional[int] = None
     hlo: Optional[Callable[[], Tuple[str, str]]] = None
     program_keys: Optional[frozenset] = None
     program_bound: Optional[int] = None
@@ -153,8 +153,8 @@ _MM = 8
 _FLASH = (2, 8, 8)          # (batch*heads, seq, head_dim)
 _FLASH_BLOCK = 8
 
-#: tiny serving config: max_slots deliberately != n_layers so the
-#: decode-is-scan trip-count check cannot alias the layer scan.
+#: tiny serving config (trace-decode-is-batched keys on slot-major scan
+#: operands, not on trip count, so max_slots may equal n_layers)
 _SLOTS = 3
 _MAX_LEN = 16
 _CHUNK = 4
@@ -193,7 +193,7 @@ def tiny_flash_arch():
 
 @functools.lru_cache(maxsize=None)
 def _tiny_serve():
-    """ONE tiny engine shared by every serve target (scan slot loop)."""
+    """ONE tiny engine shared by every serve target."""
     from repro.serve import EngineConfig, InferenceEngine
 
     return InferenceEngine(
@@ -338,7 +338,7 @@ def _decode_tick_build() -> TraceArtifact:
     engine = _tiny_serve()
     fn, args = engine.trace_tick()
     return TraceArtifact(jaxpr=jax.make_jaxpr(fn)(*args),
-                         slot_scan_length=engine.ec.max_slots)
+                         slot_width=engine.ec.max_slots)
 
 
 @functools.lru_cache(maxsize=None)
@@ -403,9 +403,9 @@ def _prefill_family_build() -> TraceArtifact:
 
 @functools.lru_cache(maxsize=None)
 def _paged_decode_tick_build() -> TraceArtifact:
-    """The paged decode tick: page-table gather -> the SAME pinned
-    decode body -> one-page scatter, threaded through the slot scan as
-    a carry. trace-decode-is-scan pins the trip count; the allocator
+    """The paged decode tick: one batched page-table gather -> the SAME
+    pinned batched decode body -> one scatter of a page per slot.
+    trace-decode-is-batched pins the body's width; the allocator
     never appears here (reservation is host-side, at admission), so
     trace-no-host-callback doubles as the no-host-sync guard on the
     allocator boundary."""
@@ -414,7 +414,7 @@ def _paged_decode_tick_build() -> TraceArtifact:
         "the audit's paged engine resolved to the dense layout")
     fn, args = engine.trace_tick()
     return TraceArtifact(jaxpr=jax.make_jaxpr(fn)(*args),
-                         slot_scan_length=engine.ec.max_slots)
+                         slot_width=engine.ec.max_slots)
 
 
 @functools.lru_cache(maxsize=None)
@@ -572,8 +572,8 @@ for _t in (
                "keeps the same O(#buckets) bound"),
     Target(id="serve.paged_decode_tick", build=_paged_decode_tick_build,
            tags=("serve", "decode"),
-           doc="paged decode tick: page-table gather/scatter around the "
-               "pinned decode body, cache pool as the slot-scan carry"),
+           doc="paged decode tick: batched page-table gather/scatter "
+               "around the pinned batched decode body"),
     Target(id="serve.paged_prefill.w4", build=_paged_prefill_build,
            tags=("serve", "prefill", "shared-block"),
            doc="paged prefix-resume prefill program (table + reserved-"

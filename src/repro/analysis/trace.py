@@ -30,10 +30,12 @@ trace-barrier-pinned           the registered shared block body traces
                                and its exact primitive sequence appears
                                contiguously in both the kernel and the
                                oracle trace
-trace-decode-is-scan           the decode tick lowers to ONE lax.scan
-                               over the slot axis (the bitwise
-                               slot-placement guarantee's mechanism), not
-                               a vmapped/unrolled body
+trace-decode-is-batched        the decode tick traces ONE batched decode
+                               body of leading width max_slots, pinned
+                               by optimization_barrier on its inputs and
+                               outputs (the bitwise slot-placement
+                               guarantee's mechanism), and no per-slot
+                               scan
 trace-accum-dtype              every float-carrying equation in kernel
                                traces uses the resolved
                                Policy.compute_dtype
@@ -260,16 +262,54 @@ def _check_barrier_pinned(target, art) -> Iterator[Violation]:
                      f"— the body traced differently in context")
 
 
-def _check_decode_is_scan(target, art) -> Iterator[Violation]:
-    if art.jaxpr is None or art.slot_scan_length is None:
+def _is_slot_pin(eqn, n: int) -> bool:
+    """An ``optimization_barrier`` over slot-batched operands: every
+    non-scalar operand has an axis of width ``n`` and at least one (a
+    per-slot vector: tokens, positions, sampled tokens, logits) leads
+    with it."""
+    if eqn.primitive.name != "optimization_barrier":
+        return False
+    shapes = [tuple(v.aval.shape) for v in eqn.invars]
+    return (all(n in sh for sh in shapes if sh)
+            and any(sh[:1] == (n,) for sh in shapes))
+
+
+def _is_slot_scan(eqn, n: int) -> bool:
+    """A ``scan`` over the slot axis: its scanned operands are slot-major,
+    i.e. they hold the per-slot request vectors, an integer one (tokens,
+    positions, seeds) and a floating one (temperatures), each of shape
+    exactly ``[n]``. The trip count alone never decides: a layer scan or
+    a per-layer ``lax.map`` whose length equals ``n`` (olmo-1b: 16
+    layers, 16 slots) scans weights, cache leaves or one index vector,
+    so it does not match."""
+    if eqn.primitive.name != "scan" or eqn.params["length"] != n:
+        return False
+    first = eqn.params["num_consts"] + eqn.params["num_carry"]
+    vecs = [v.aval.dtype for v in eqn.invars[first:]
+            if tuple(v.aval.shape) == (n,)]
+    return (any(np.issubdtype(d, np.integer) for d in vecs)
+            and any(np.issubdtype(d, np.floating) for d in vecs))
+
+
+def _check_decode_is_batched(target, art) -> Iterator[Violation]:
+    if art.jaxpr is None or art.slot_width is None:
         return
-    n = art.slot_scan_length
-    if n not in scan_lengths(art.jaxpr):
-        yield _v(target, "trace-decode-is-scan",
-                 f"decode tick does not lower to a lax.scan of length "
-                 f"{n} over the slot axis (vmapped or unrolled body — "
-                 f"per-slot rounding is then up to the backend "
-                 f"vectorizer)")
+    n = art.slot_width
+    if any(_is_slot_scan(eqn, n) for eqn, _ in iter_eqns(art.jaxpr)):
+        yield _v(target, "trace-decode-is-batched",
+                 f"decode tick holds a lax.scan of length {n} over "
+                 f"per-slot operands: a per-slot scan of a batch-1 body, "
+                 f"which reads every weight once per slot")
+    pins = [path for eqn, path in iter_eqns(art.jaxpr)
+            if _is_slot_pin(eqn, n)]
+    if len(pins) != 2 or any("scan" in p.split("/") for p in pins):
+        yield _v(target, "trace-decode-is-batched",
+                 f"decode tick traces {len(pins)} optimization_barrier "
+                 f"pins over width-{n} slot operands outside any scan, "
+                 f"not the 2 (entry and exit) of ONE pinned batched "
+                 f"decode body — an unpinned body leaves per-row "
+                 f"rounding to XLA's fusion of the data movement around "
+                 f"it")
 
 
 def _check_accum_dtype(target, art) -> Iterator[Violation]:
@@ -356,13 +396,15 @@ for _rule in (
             "identical contiguous primitive sequence in kernel and oracle",
     ),
     TraceRule(
-        id="trace-decode-is-scan",
+        id="trace-decode-is-batched",
         tags=("decode",),
-        checker=_check_decode_is_scan,
-        fix_hint="keep EngineConfig.slot_loop='scan' (vmap forfeits the "
-                 "bitwise slot-placement guarantee)",
-        doc="the decode tick lowers to ONE lax.scan over the slot axis "
-            "with a single shared body",
+        checker=_check_decode_is_batched,
+        fix_hint="run decode_one through the engine's decode_slots: one "
+                 "vmap over the max_slots axis, pinned on its inputs and "
+                 "outputs",
+        doc="the decode tick traces exactly ONE barrier-pinned batched "
+            "decode body of leading width max_slots, and no per-slot "
+            "scan",
     ),
     TraceRule(
         id="trace-accum-dtype",

@@ -70,7 +70,7 @@ def cache_batch_axes(cache_specs: Params) -> Params:
     structure as the cache), which is the slot-addressing contract the
     serving engine builds on: ``repro.serve.slots`` uses it both as the
     scatter axis for per-slot cache writes/resets and as the ``vmap``
-    in/out axes for the per-slot decode tick. Leaves that do not mark a
+    in/out axes for the batched decode tick. Leaves that do not mark a
     batch axis fail fast here, at engine construction — never inside a
     trace.
     """

@@ -52,18 +52,20 @@ registered compensation scheme. Four mechanisms carry it:
   schedule is a pure function of (prompt_len, prefill_chunk): scheduler
   choices (budget, interleaving, slot placement) cannot leak into a
   request's bits;
-* the decode tick maps ONE single-request decode body over the slot
-  axis (per-slot cache row, token, position, sampling key) — by default
-  as a ``lax.scan`` whose body compiles ONCE, so every slot executes
-  the identical instruction (and rounding) sequence regardless of which
-  slot a request landed in (``jax.vmap`` keeps per-slot math
-  row-independent in exact arithmetic, but XLA's fusion autotuning may
-  vectorize different batch rows through different code paths —
-  measured: ~1-ulp logit drift on the hybrid SSM decode.
-  ``EngineConfig.slot_loop="vmap"`` opts into the fully parallel tick
-  for throughput work that doesn't need the bitwise guarantee). The
+* the decode tick runs ONE pinned batched decode body over the fixed
+  ``max_slots`` slot axis: the single-request ``decode_one`` mapped over
+  the slots with ``jax.vmap``, with ``lax.optimization_barrier`` pins on
+  the whole batched call's inputs and outputs. The width never changes
+  with occupancy, so a solo request runs the same program as an
+  interleaved one and only its row index differs; and nothing in the
+  body reduces across the slot axis (matmuls, norms, attention and
+  sampling are all row-local), so a row's bits cannot depend on its
+  neighbours. That every row of a batched op rounds alike, whatever its
+  index, is the backend's part: the serving tests check it on the CPU
+  and ``chip_smoke.py`` at 4 and 16 slots on the TPU. Every slot shares
+  one read of the weights per tick. The
   tick updates ONLY the rows of RUNNING slots — free and PREFILLING
-  rows keep their bits through an exact post-scan select, which is what
+  rows keep their bits through an exact post-body select, which is what
   lets a partially prefilled row live in the slot cache while its
   neighbours decode;
 * prefill chunk programs operate on the request's own batch-1 row
@@ -115,10 +117,10 @@ memory scales with live tokens and one compiled program serves every
 page placement. THE DENSE LAYOUT REMAINS THE DEFAULT AND THE BITWISE
 ORACLE: a request's tokens and telemetry are bitwise identical under
 either layout, and identical whether its pages are contiguous or
-scattered — carried by pinning ``decode_one`` and the prefill chunk
-body with ``optimization_barrier`` in BOTH layouts (identical pinned
-interiors; only the exact-data-movement gather/scatter differs) plus
-the zero-fill gather / zero-reset-on-free pristine-bits guarantee.
+scattered — carried by the same ``optimization_barrier``-pinned
+batched decode body and prefill chunk body in BOTH layouts (identical
+pinned interiors; only the exact-data-movement gather/scatter differs)
+plus the zero-fill gather / zero-reset-on-free pristine-bits guarantee.
 Page reservation is whole-request at admission (never in a trace,
 never mid-decode; exhaustion blocks admission FIFO — the ALLOCATING
 state), and ``EngineConfig.prefix_cache`` adds a refcounted radix tree
@@ -149,6 +151,7 @@ from repro.serve.paging import (
     PageAllocator,
     PagedKVCache,
     paged_gather_row,
+    paged_gather_rows,
     paged_scatter_decode,
     paged_scatter_row,
     pages_for,
@@ -162,7 +165,13 @@ from repro.serve.scheduler import (
     SamplingParams,
     SlotScheduler,
 )
-from repro.serve.slots import SlotKVCache, _donate, gather_row, scatter_row
+from repro.serve.slots import (
+    SlotKVCache,
+    _donate,
+    gather_row,
+    scatter_row,
+    select_live,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,11 +187,6 @@ class EngineConfig:
                    at engine construction
     sample_seed    seed of the engine-level sampling key; per-request
                    streams fold their ``SamplingParams.seed`` into it
-    slot_loop      how the decode tick maps the single-request body over
-                   slots: "scan" (default — one traced body, identical
-                   rounding per slot, carries the bitwise contract) or
-                   "vmap" (fully parallel rows; bitwise slot-placement
-                   invariance is then up to the backend's vectorizer)
     prefill_chunk  prompt-chunk width for chunked prefill (the compiled
                    prefill program set is {prefill_chunk} plus power-of-
                    two tail buckets below it). None = legacy one-shot
@@ -220,8 +224,7 @@ class EngineConfig:
                    live tokens). Families with no pageable leaf
                    (SSM/xLSTM recurrence, all-window hybrids) fall back
                    to dense; ``InferenceEngine.kv_layout`` reports the
-                   resolved layout. Requires slot_loop="scan" (the
-                   paged tick threads the pool through the slot scan)
+                   resolved layout
     page_size      positions per page (power of two; max_len must be a
                    multiple). Smaller pages track live tokens tighter
                    and share prefixes at finer grain; larger pages cut
@@ -242,7 +245,6 @@ class EngineConfig:
     track_stats: bool = False
     policy: Optional[Policy] = None
     sample_seed: int = 0
-    slot_loop: str = "scan"
     prefill_chunk: Optional[int] = 64
     prefill_budget: Optional[int] = None
     max_finished: Optional[int] = None
@@ -253,9 +255,6 @@ class EngineConfig:
     prefix_cache: bool = False
 
     def __post_init__(self):
-        if self.slot_loop not in ("scan", "vmap"):
-            raise ValueError(
-                f"slot_loop must be 'scan' or 'vmap', got {self.slot_loop!r}")
         if self.prefill_mode not in ("scan", "flash"):
             raise ValueError(
                 f"prefill_mode must be 'scan' or 'flash', "
@@ -279,11 +278,6 @@ class EngineConfig:
                 raise ValueError(
                     f"num_pages must be >= 1 (or None for dense parity), "
                     f"got {self.num_pages}")
-            if self.slot_loop == "vmap":
-                raise ValueError(
-                    "kv_layout='paged' requires slot_loop='scan' — the "
-                    "paged decode tick threads the page pool through the "
-                    "slot scan as a carry")
         if self.prefix_cache and self.kv_layout != "paged":
             raise ValueError(
                 "prefix_cache=True requires kv_layout='paged' (prefix "
@@ -419,7 +413,8 @@ def _compiled_fns(model, cfg: ArchConfig, ec: EngineConfig, policy: Policy,
     as a traced operand and assemble/write its logical row through
     ``repro.serve.paging`` — one compiled program for ANY page
     placement. The compute between gather and scatter is the same
-    barrier-pinned ``decode_one`` / chunk body the dense programs run.
+    barrier-pinned batched decode body / chunk body the dense programs
+    run.
     """
     # Resolve the chunk body ONCE: "flash" engines over a family whose
     # recurrence forces per-position stepping (hybrid ring/SSM, xLSTM —
@@ -435,7 +430,7 @@ def _compiled_fns(model, cfg: ArchConfig, ec: EngineConfig, policy: Policy,
         prefill_body = "flash"
     layout = "dense" if page_axes is None else ("paged", ec.page_size)
     key = ("serve", ec.max_slots, ec.max_len, ec.track_stats,
-           ec.sample_seed, ec.slot_loop, policy, prefill_body, layout)
+           ec.sample_seed, policy, prefill_body, layout)
     cache = model.__dict__.setdefault("_serve_compiled", {})
     if key in cache:
         return cache[key]
@@ -463,23 +458,10 @@ def _compiled_fns(model, cfg: ArchConfig, ec: EngineConfig, policy: Policy,
         return activation_sq_norm(logits[:, :vocab], scheme=policy)
 
     def decode_one(params, cache_row, token, pos, seed, eidx, temp):
-        """ONE request's decode step — the unit mapped over slots.
-        Re-inserts the request axis (size 1) per cache leaf, runs the
-        model's own decode_step, samples with the request's folded key.
-        Entry/exit are ``optimization_barrier``-pinned: the dense tick
-        feeds rows via moveaxis slicing, the paged tick via page-table
-        gathers, and the pin keeps XLA from fusing either data-movement
-        flavour INTO the arithmetic — both layouts execute this
-        identical interior, which is the paged-vs-dense half of the
-        serving bitwise contract (module docstring). (The "vmap" slot
-        loop skips the pin — optimization_barrier has no batching rule,
-        and that loop opts out of the bitwise contract anyway.)
-        """
-        pin = ec.slot_loop != "vmap"
-        if pin:
-            cache_row, token, pos, seed, eidx, temp = (
-                jax.lax.optimization_barrier(
-                    (cache_row, token, pos, seed, eidx, temp)))
+        """ONE request's decode step — the row the batched body maps over
+        the slots. Re-inserts the request axis (size 1) per cache leaf,
+        runs the model's own decode_step, samples with the request's
+        folded key."""
         cache1 = jax.tree.map(lambda x, a: jnp.expand_dims(x, a),
                               cache_row, batch_axes)
         logits, new_cache = model.decode_step(params, cache1, token[None],
@@ -488,78 +470,52 @@ def _compiled_fns(model, cfg: ArchConfig, ec: EngineConfig, policy: Policy,
                                new_cache, batch_axes)
         k = jax.random.fold_in(jax.random.fold_in(base_key, seed), eidx)
         tok = sample_row(logits[0], k, temp)
-        out = (logits[0], new_row, tok)
-        return jax.lax.optimization_barrier(out) if pin else out
+        return logits[0], new_row, tok
+
+    decode_rows = jax.vmap(decode_one,
+                           in_axes=(None, batch_axes, 0, 0, 0, 0, 0),
+                           out_axes=(0, batch_axes, 0))
+
+    def decode_slots(params, rows, tokens, pos, seeds, eidx, temps):
+        """The pinned batched decode body: ``decode_one`` over all
+        ``max_slots`` rows at once, so the weights are read once a tick.
+        The width is fixed and no op reduces across rows, so a row's
+        bits depend on its own inputs only. The pins sit around the
+        whole batched call (``optimization_barrier`` has no batching
+        rule): the dense tick feeds the cache as it is, the paged tick
+        rows gathered through page tables, and the pins keep XLA from
+        fusing either data movement INTO the arithmetic — both layouts
+        execute this identical interior, which is the paged-vs-dense
+        half of the serving bitwise contract (module docstring)."""
+        args = jax.lax.optimization_barrier(
+            (rows, tokens, pos, seeds, eidx, temps))
+        return jax.lax.optimization_barrier(decode_rows(params, *args))
+
+    def _norms_or_zeros(logits):
+        return (_norms(logits) if ec.track_stats
+                else jnp.zeros((ec.max_slots,), jnp.float32))
 
     if page_axes is not None:
-        # ------------------------------------------------------ paged tick
-        # The cache pytree (dense rows + page pools) is the scan CARRY;
-        # per-slot xs carry the request's page table and reserved-page
-        # count. Each step gathers the slot's logical row through its
-        # table (dense leaves slice at the slot), runs the SAME pinned
-        # decode_one, selects old bits back for dead slots IN-BODY (the
-        # dense tick's post-scan keep, moved inside the carry), and
-        # scatters dense leaves at the slot plus exactly ONE pool page —
-        # the one containing ``pos`` (dead slots write the NULL page).
+        # Paged tick: gather every slot's logical row through its table
+        # in one batched gather (dense leaves are already rows), run the
+        # SAME pinned body, then write back exactly ONE pool page per
+        # slot — the one containing ``pos`` — in one scatter (dead slots
+        # write the NULL page; dense leaves keep dead slots' old bits).
         @functools.partial(jax.jit, donate_argnums=tuple(
             1 + i for i in _donate()))
         def tick(params, cache, tokens, pos, seeds, eidx, temps, live,
                  tables, nres):
             with use_policy(policy):
-                slots_iota = jnp.arange(ec.max_slots, dtype=jnp.int32)
-
-                def body(carry, xs):
-                    token, p, seed, ei, temp, lv, table, nr, slot = xs
-                    row1 = paged_gather_row(carry, batch_axes, page_axes,
-                                            slot, table, nr)
-                    row = jax.tree.map(lambda x, a: jnp.squeeze(x, a),
-                                       row1, batch_axes)
-                    lg, new_row, tok = decode_one(params, row, token, p,
-                                                  seed, ei, temp)
-                    new1 = jax.tree.map(lambda x, a: jnp.expand_dims(x, a),
-                                        new_row, batch_axes)
-                    # dead slots keep their old bits — exact select, and
-                    # their pool write is redirected to the NULL page
-                    new1 = jax.tree.map(lambda n, o: jnp.where(lv, n, o),
-                                        new1, row1)
-                    carry = paged_scatter_decode(
-                        carry, new1, batch_axes, page_axes, slot, table,
-                        p, lv)
-                    return carry, (lg, tok)
-
-                new_cache, (logits, next_tok) = jax.lax.scan(
-                    body, cache, (tokens, pos, seeds, eidx, temps, live,
-                                  tables, nres, slots_iota))
-                norms = (_norms(logits) if ec.track_stats
-                         else jnp.zeros((ec.max_slots,), jnp.float32))
+                rows = paged_gather_rows(cache, batch_axes, page_axes,
+                                         tables, nres)
+                logits, new_rows, next_tok = decode_slots(
+                    params, rows, tokens, pos, seeds, eidx, temps)
+                new_cache = paged_scatter_decode(
+                    cache, new_rows, batch_axes, page_axes, tables, pos,
+                    live)
+                norms = _norms_or_zeros(logits)
             return new_cache, next_tok, norms
-
-        decode_slots = None
-    elif ec.slot_loop == "vmap":
-        decode_slots = jax.vmap(decode_one,
-                                in_axes=(None, batch_axes, 0, 0, 0, 0, 0),
-                                out_axes=(0, batch_axes, 0))
     else:
-        def decode_slots(params, cache, tokens, pos, seeds, eidx, temps):
-            # ONE traced body scanned over the slot axis: every slot runs
-            # the identical rounding sequence, so a request's bits cannot
-            # depend on which slot the scheduler gave it (vmap leaves
-            # that to the backend vectorizer — see the module docstring).
-            front = jax.tree.map(lambda x, a: jnp.moveaxis(x, a, 0),
-                                 cache, batch_axes)
-
-            def body(_, xs):
-                row, token, p, seed, ei, temp = xs
-                out = decode_one(params, row, token, p, seed, ei, temp)
-                return None, out
-
-            _, (logits, new_front, toks) = jax.lax.scan(
-                body, None, (front, tokens, pos, seeds, eidx, temps))
-            new_cache = jax.tree.map(lambda x, a: jnp.moveaxis(x, 0, a),
-                                     new_front, batch_axes)
-            return logits, new_cache, toks
-
-    if decode_slots is not None:
         @functools.partial(jax.jit, donate_argnums=tuple(
             1 + i for i in _donate()))
         def tick(params, cache, tokens, pos, seeds, eidx, temps, live):
@@ -569,17 +525,12 @@ def _compiled_fns(model, cfg: ArchConfig, ec: EngineConfig, policy: Policy,
                 # ONLY running slots advance: free and PREFILLING rows
                 # keep their bits (a partially prefilled row must not be
                 # stomped by the garbage compute of its own tick lane).
-                # The select is exact and applied OUTSIDE the scanned
+                # The select is exact and applied OUTSIDE the pinned
                 # body, so live rows' bits are untouched.
-                def keep(new, old, a):
-                    shape = [1] * new.ndim
-                    shape[a] = live.shape[0]
-                    return jnp.where(live.reshape(shape), new, old)
-
-                new_cache = jax.tree.map(keep, new_cache, cache,
-                                         batch_axes)
-                norms = (_norms(logits) if ec.track_stats
-                         else jnp.zeros((ec.max_slots,), jnp.float32))
+                new_cache = jax.tree.map(
+                    lambda n, o, a: select_live(n, o, a, live),
+                    new_cache, cache, batch_axes)
+                norms = _norms_or_zeros(logits)
             return new_cache, next_tok, norms
 
     begin = getattr(model, "prefill_begin", None)

@@ -51,6 +51,14 @@ def _take_leaf(big: jax.Array, axis: int, slot) -> jax.Array:
     return jax.lax.dynamic_slice(big, tuple(starts), sizes)
 
 
+def select_live(new: jax.Array, old: jax.Array, axis: int, live) -> jax.Array:
+    """``new`` in the rows whose ``live`` flag is set, ``old`` elsewhere:
+    an exact select along the request axis ``axis`` (``live`` [max_slots])."""
+    shape = [1] * new.ndim
+    shape[axis] = live.shape[0]
+    return jnp.where(live.reshape(shape), new, old)
+
+
 def gather_row(cache: Any, axes: Any, slot) -> Any:
     """Extract slot ``slot`` as a batch-1 row cache (pure; traceable)."""
     return jax.tree.map(lambda big, a: _take_leaf(big, a, slot), cache, axes)

@@ -143,26 +143,95 @@ def test_barrier_pinned_silent_when_body_is_embedded():
 
 
 # ---------------------------------------------------------------------------
-# trace-decode-is-scan: the vmap'd tick the AST layer cannot flag
+# trace-decode-is-batched: one pinned batched body, no per-slot scan
 # ---------------------------------------------------------------------------
 
-def test_decode_is_scan_fires_on_vmap_engine():
-    from repro.serve import EngineConfig, InferenceEngine
+def _scan_tick_trace():
+    """The per-slot scan tick (the test oracle) over the audit's engine."""
+    from scan_tick_oracle import scan_tick
 
-    eng = InferenceEngine(
-        targets.tiny_arch(),
-        EngineConfig(max_slots=3, max_len=16, prefill_chunk=4,
-                     slot_loop="vmap"))
+    eng = targets._tiny_serve()
     fn, args = eng.trace_tick()
-    art = targets.TraceArtifact(jaxpr=jax.make_jaxpr(fn)(*args),
-                                slot_scan_length=eng.ec.max_slots)
-    found = _fired("trace-decode-is-scan", ("decode",), art)
-    assert found and "lax.scan" in found[0].message
+    return jax.make_jaxpr(scan_tick(eng))(*args), eng.ec.max_slots
 
 
-def test_decode_is_scan_silent_on_registered_tick():
-    report = trace.audit(target_ids=["serve.decode_tick"],
-                         rule_ids=["trace-decode-is-scan"])
+def _unpinned_tick_trace():
+    """A batched tick with no pins: one vmapped row body, no barrier."""
+    rows, toks = _sds((3, 8)), _sds((3,), jnp.int32)
+    body = jax.vmap(lambda r, t: (r * 2.0, t + 1))
+    return jax.make_jaxpr(body)(rows, toks), 3
+
+
+@pytest.mark.parametrize("tick, message", [
+    (_scan_tick_trace, "lax.scan of length"),
+    (_unpinned_tick_trace, "0 optimization_barrier pins"),
+])
+def test_decode_is_batched_fires_on_scan_or_unpinned_tick(tick, message):
+    jaxpr, width = tick()
+    art = targets.TraceArtifact(jaxpr=jaxpr, slot_width=width)
+    found = _fired("trace-decode-is-batched", ("decode",), art)
+    assert found and any(message in f.message for f in found), (
+        [f.message for f in found])
+
+
+def test_decode_is_batched_silent_on_layer_scan_of_slot_width():
+    """A pinned batched tick whose layer scan and per-layer ``lax.map``
+    have as many steps as there are slots (olmo-1b: 16 and 16) is not a
+    per-slot scan."""
+    n = 4
+    weights = _sds((n, 8))
+
+    def row(r, t, w):
+        def layer(x, w_l):
+            return x * w_l, None
+
+        x, _ = jax.lax.scan(layer, r, w)
+        x = x + jax.lax.map(lambda i: i.astype(jnp.float32),
+                            jnp.arange(n)).sum()
+        return x, t + 1
+
+    def tick(rows, toks, w):
+        rows, toks = jax.lax.optimization_barrier((rows, toks))
+        out = jax.vmap(row, in_axes=(0, 0, None))(rows, toks, w)
+        return jax.lax.optimization_barrier(out)
+
+    jaxpr = jax.make_jaxpr(tick)(_sds((n, 8)), _sds((n,), jnp.int32),
+                                 weights)
+    art = targets.TraceArtifact(jaxpr=jaxpr, slot_width=n)
+    assert _fired("trace-decode-is-batched", ("decode",), art) == []
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_decode_is_batched_when_layers_equal_slots(layout):
+    """An engine with as many layers as slots (olmo-1b: 16 and 16): its
+    layer scan, and in the paged layout the per-layer gather, run
+    ``max_slots`` steps, and the rule stays silent on its tick. The
+    per-slot scan oracle over the same dense engine still fires."""
+    from repro.serve import EngineConfig, InferenceEngine
+    from scan_tick_oracle import scan_tick
+
+    n = 4
+    eng = InferenceEngine(
+        targets.tiny_arch().replace(n_layers=n),
+        EngineConfig(max_slots=n, max_len=16, prefill_chunk=4,
+                     kv_layout=layout, page_size=4))
+    assert eng.kv_layout == layout
+    fn, args = eng.trace_tick()
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    assert n in trace.scan_lengths(jaxpr)
+    art = targets.TraceArtifact(jaxpr=jaxpr, slot_width=n)
+    assert _fired("trace-decode-is-batched", ("decode",), art) == []
+    if layout == "dense":
+        oracle = targets.TraceArtifact(
+            jaxpr=jax.make_jaxpr(scan_tick(eng))(*args), slot_width=n)
+        found = _fired("trace-decode-is-batched", ("decode",), oracle)
+        assert any("lax.scan of length" in f.message for f in found)
+
+
+def test_decode_is_batched_silent_on_registered_tick():
+    report = trace.audit(
+        target_ids=["serve.decode_tick", "serve.paged_decode_tick"],
+        rule_ids=["trace-decode-is-batched"])
     assert report.violations == [], [v.format() for v in report.violations]
 
 

@@ -125,6 +125,120 @@ def test_runtime_registered_scheme_serves_bitwise(tiny_model):
 
 
 # ---------------------------------------------------------------------------
+# The batched tick: slot placement, and the per-slot scan oracle
+# ---------------------------------------------------------------------------
+
+def _serve_in_slot(cfg, ec, model, params, req, slot, full):
+    """Serve ``req`` in ``slot`` of a fresh engine: fillers submitted
+    first take the slots below it (lowest free slot first). ``full``
+    keeps every slot decoding beside it; otherwise the fillers finish at
+    their first token, inside the admitting step, and ``req`` decodes
+    alone. Returns its handle."""
+    eng = InferenceEngine(cfg, ec, model=model, params=params)
+    fill = req.sampling.max_new_tokens + 2 if full else 1
+    rng = np.random.default_rng(100 + slot)
+    fillers = [Request(prompt=rng.integers(0, cfg.vocab_size, (4 + i,))
+                       .astype(np.int32),
+                       sampling=SamplingParams(max_new_tokens=fill),
+                       request_id=1000 + i)
+               for i in range(ec.max_slots - 1)]
+    for f in fillers[:slot]:
+        eng.submit(f)
+    h = eng.submit(req)
+    for f in fillers[slot:] if full else ():
+        eng.submit(f)
+    occupancy = set()
+    while not h.done:
+        eng.step()
+        if not h.done and h.slot is not None:
+            occupancy.add(len(eng.scheduler.running))
+    assert occupancy == {ec.max_slots if full else 1}, occupancy
+    return h, eng
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_slot_placement_bitwise(tiny_model, layout):
+    """One request served in every slot of a 4-slot engine, alone and
+    beside three neighbours, under either KV layout: its tokens and
+    telemetry are bitwise those of slot 0 alone. The batched tick gives
+    every slot the same program; only the row index moves."""
+    cfg, model, params = tiny_model
+    ec = EngineConfig(max_slots=4, max_len=32, track_stats=True,
+                      prefill_chunk=4, kv_layout=layout, page_size=4,
+                      policy=Policy(scheme="kahan", unroll=2))
+    req = _requests(cfg, [(7, 6)], seed=11, temperature=0.7)[0]
+    ref, eng = _serve_in_slot(cfg, ec, model, params, req, 0, full=False)
+    assert eng.kv_layout == layout
+    assert len(ref.tokens) == 6 and all(np.isfinite(ref.telemetry))
+    for full in (False, True):
+        for slot in range(ec.max_slots):
+            h, _ = _serve_in_slot(cfg, ec, model, params, req, slot, full)
+            where = f"slot {slot}, {'full' if full else 'alone'}"
+            assert h.tokens == ref.tokens, f"{where}: tokens differ"
+            assert h.telemetry == ref.telemetry, f"{where}: telemetry differs"
+
+
+#: batched tick against the per-slot scan oracle, float32 tiny model: the
+#: largest gap either way, relative to the oracle's largest magnitude
+ORACLE_RTOL = 1e-5
+
+
+def test_batched_tick_matches_scan_oracle(tiny_model):
+    """The engine's tick against the per-slot scan tick it replaced, on
+    the same mid-flight cache with two live slots of four: tokens exact,
+    the logits, the compensated |logits|^2 and the written cache within
+    ``ORACLE_RTOL``, and the dead slots' rows bitwise unchanged."""
+    from scan_tick_oracle import scan_tick, tick_logits
+
+    cfg, model, params = tiny_model
+    ec = EngineConfig(max_slots=4, max_len=16, track_stats=True,
+                      policy=Policy(scheme="kahan", unroll=2))
+    eng = InferenceEngine(cfg, ec, model=model, params=params)
+    for req in _requests(cfg, [(5, 8), (9, 8)], seed=5, temperature=0.7):
+        eng.submit(req)
+    for _ in range(3):
+        eng.step()
+    running = eng.scheduler.running
+    assert sorted(running) == [0, 1]
+    b = ec.max_slots
+    tokens, pos, seeds, eidx = (np.zeros((b,), np.int32) for _ in range(4))
+    temps, live = np.zeros((b,), np.float32), np.zeros((b,), bool)
+    for slot, h in running.items():
+        tokens[slot], pos[slot], seeds[slot] = h.tokens[-1], h.pos, h.seed
+        eidx[slot] = h.emitted
+        temps[slot] = h.request.sampling.temperature
+        live[slot] = True
+    args = tuple(jnp.asarray(a) for a in (tokens, pos, seeds, eidx, temps,
+                                          live))
+    cache = jax.tree.map(jnp.copy, eng.slots.cache)
+    want_cache, want_tok, want_norms, want_logits = scan_tick(eng)(
+        params, cache, *args)
+    got_logits = tick_logits(eng._fns.tick, params, cache, *args)
+    got_cache, got_tok, got_norms = eng._fns.tick(
+        params, jax.tree.map(jnp.copy, cache), *args)
+
+    np.testing.assert_array_equal(np.asarray(got_tok)[live],
+                                  np.asarray(want_tok)[live])
+
+    def close(got, want):
+        got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+        scale = max(float(np.max(np.abs(want))), 1e-30)
+        assert float(np.max(np.abs(got - want))) <= ORACLE_RTOL * scale
+
+    vocab = cfg.vocab_size
+    close(np.asarray(got_logits)[live, :vocab],
+          np.asarray(want_logits)[live, :vocab])
+    close(np.asarray(got_norms)[live], np.asarray(want_norms)[live])
+    jax.tree.map(close, got_cache, want_cache)
+    dead = [s for s in range(b) if not live[s]]
+    jax.tree.map(
+        lambda g, o, a: np.testing.assert_array_equal(
+            np.take(np.asarray(g), dead, axis=a),
+            np.take(np.asarray(o), dead, axis=a)),
+        got_cache, cache, eng.slots.batch_axes)
+
+
+# ---------------------------------------------------------------------------
 # Slot reuse after eviction
 # ---------------------------------------------------------------------------
 
@@ -679,11 +793,12 @@ def test_pop_finished_drains_default_retention(tiny_model):
 @pytest.mark.slow  # full hybrid compile: ring KV + SSM + global attn
 def test_hybrid_ring_and_ssm_state_bitwise():
     """The slot cache carries ring-buffer KV and SSM recurrent state;
-    the scan slot loop keeps the contract even where vmap's batch
-    vectorization drifts by an ulp (the measured hybrid failure mode).
-    Chunked prefill rides the same contract: the 9-token prompt wraps
-    the window-8 ring buffer mid-chunk and must still match one-shot
-    admit bitwise."""
+    the pinned batched decode tick keeps the contract for them: a
+    request's bits do not depend on its slot or its neighbours (an
+    unpinned vmap tick once drifted by an ulp here, the measured hybrid
+    failure mode). Chunked prefill rides the same contract: the 9-token
+    prompt wraps the window-8 ring buffer mid-chunk and must still match
+    one-shot admit bitwise."""
     cfg = ArchConfig(name="tiny-hybrid", family="hybrid", n_layers=2,
                      d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
                      vocab_size=128, sliding_window=8,
@@ -733,16 +848,14 @@ def test_submit_validation(tiny_model):
         eng.submit(Request(prompt=np.arange(4, dtype=np.int32),
                            request_id=7,
                            sampling=SamplingParams(max_new_tokens=2)))
-    with pytest.raises(ValueError, match="slot_loop"):
-        EngineConfig(slot_loop="bogus")
     with pytest.raises(ValueError, match="max_slots"):
         InferenceEngine(cfg, EngineConfig(max_slots=0), model=model,
                         params=params)
 
 
 def test_engine_config_validation():
-    """The serving knobs validate in ``__post_init__`` alongside the
-    slot_loop check — bad values fail at construction, not mid-trace."""
+    """The serving knobs validate in ``__post_init__`` — bad values fail
+    at construction, not mid-trace."""
     with pytest.raises(ValueError, match="max_len"):
         EngineConfig(max_len=0)
     with pytest.raises(ValueError, match="prefill_chunk"):

@@ -450,9 +450,6 @@ def test_config_validation():
         EngineConfig(kv_layout="ragged")
     with pytest.raises(ValueError, match="prefix_cache"):
         EngineConfig(prefix_cache=True)
-    with pytest.raises(ValueError, match="slot_loop"):
-        EngineConfig(kv_layout="paged", page_size=16, max_len=32,
-                     slot_loop="vmap")
     with pytest.raises(ValueError, match="num_pages"):
         EngineConfig(kv_layout="paged", num_pages=0)
 
