@@ -40,7 +40,7 @@ def main():
 
     print()
     print("=" * 72)
-    print("(c) TPU transplant (DESIGN.md §2): v4 / v5e / v5p")
+    print("(c) TPU transplant: v4 / v5e / v5p")
     print("=" * 72)
     for m in (ecm.TPU_V4, ecm.TPU_V5E, ecm.TPU_V5P):
         for kern in (ecm.NAIVE_DOT_TPU, ecm.KAHAN_DOT_TPU,

@@ -1,20 +1,11 @@
 #!/usr/bin/env bash
-# Three-stage CI: tier-1 (fast, must stay < 120 s), the slow tier, and a
-# benchmarks smoke stage (tiny shapes, interpret mode — every registered
-# benchmark in benchmarks/run.py, with the rows captured to a
-# BENCH_<date>.json artifact so the perf trajectory is tracked).
+# CI: the contract audits (stages 0, 0b, 0c), then tier-1 (stage 1) and
+# the slow tier (stage 2). Speed is measured on the chip by bench/ (see
+# BENCHMARK.json), not here.
 #
 #   scripts/ci.sh            # all stages
-#   scripts/ci.sh fast       # tier-1 only (what the driver runs)
-#   scripts/ci.sh slow       # slow tier only
-#   scripts/ci.sh bench      # benchmarks smoke stage only
-#
-# Deprecation gate: both pytest stages run with DeprecationWarning
-# promoted to an error for warnings ATTRIBUTED to repro.* modules (e.g.
-# the deprecated lock-step Server shim warns at its caller), proving no
-# internal call site leans on a deprecated surface. Test call sites that
-# deliberately exercise one attribute to the test module and stay
-# warnings.
+#   scripts/ci.sh fast       # audits + tier-1
+#   scripts/ci.sh slow       # audits + slow tier
 #
 # Contract gate: stage 0 runs the AST-based engine-contract linter
 # (repro.analysis) over src/repro — every clause of the numerics contract
@@ -42,10 +33,6 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
 stage="${1:-all}"
 
-# -o filterwarnings treats module as a REGEX (pytest CLI -W would escape
-# it to a literal full-module match and miss submodules).
-DEPRECATION_GATE=(-o 'filterwarnings=error::DeprecationWarning:repro(\..*)?')
-
 echo "=== stage 0: engine-contract lint (src/repro) ==="
 python -m repro.analysis --strict --budget 65 src/repro
 
@@ -56,18 +43,11 @@ echo "=== stage 0c: ECM cost audit (instruction mix / memory traffic) ==="
 python -m repro.analysis --cost --strict
 
 if [[ "$stage" == "fast" || "$stage" == "all" ]]; then
-    echo "=== stage 1: tier-1 (fast) + repro.* deprecation gate ==="
-    python -m pytest -x -q "${DEPRECATION_GATE[@]}"
+    echo "=== stage 1: tier-1 (fast) ==="
+    python -m pytest -x -q
 fi
 
 if [[ "$stage" == "slow" || "$stage" == "all" ]]; then
     echo "=== stage 2: slow tier ==="
-    python -m pytest -q -m slow "${DEPRECATION_GATE[@]}"
-fi
-
-if [[ "$stage" == "bench" || "$stage" == "all" ]]; then
-    echo "=== stage 3: benchmarks smoke (tiny shapes, interpret mode) ==="
-    # (the repro.* deprecation gate lives in the pytest stages; the bench
-    # modules go through the same public API they exercise)
-    python -m benchmarks.run --smoke --json "BENCH_$(date +%Y%m%d).json"
+    python -m pytest -q -m slow
 fi

@@ -607,7 +607,7 @@ def _check_memory_traffic(target, art) -> Iterator[Violation]:
 def _check_no_hidden_copies(target, art) -> Iterator[Violation]:
     if art.hlo is None:
         return
-    from repro.perf.hlo_analysis import parse_hlo
+    from repro.analysis.hlo import parse_hlo
 
     counts = parse_hlo(art.hlo()).opcode_counts()
     for op in _FORBIDDEN_HLO_OPS:

@@ -31,8 +31,6 @@ no-host-sync-in-trace       .item()/.block_until_ready() anywhere in scope,
 no-raw-prngkey              PRNG keys are created at boundary modules only
                             (train/launch/config); everything else fold_ins
                             from a key it was handed
-no-deprecated-surface       internal code must not call the deprecated
-                            lock-step train.serve.Server shim
 ==========================  =================================================
 """
 
@@ -287,25 +285,6 @@ def _check_raw_prngkey(ctx: FileContext) -> Iterator[Violation]:
                 "fold_in from an owned key")
 
 
-def _check_deprecated_surface(ctx: FileContext) -> Iterator[Violation]:
-    for node in ctx.walk():
-        if isinstance(node, ast.ImportFrom) and node.module in (
-                "repro.train.serve", "repro.train"):
-            for a in node.names:
-                if a.name == "Server":
-                    yield ctx.violation(
-                        node, "no-deprecated-surface",
-                        "imports the deprecated lock-step "
-                        "train.serve.Server shim")
-        elif isinstance(node, ast.Attribute):
-            if ctx.resolve(node) in ("repro.train.serve.Server",
-                                     "repro.train.Server"):
-                yield ctx.violation(
-                    node, "no-deprecated-surface",
-                    "references the deprecated lock-step "
-                    "train.serve.Server shim")
-
-
 for _rule in (
     Rule(
         id="no-raw-psum",
@@ -370,22 +349,13 @@ for _rule in (
     Rule(
         id="no-raw-prngkey",
         scope=("models/*", "kernels/*", "optim/*", "distributed/*",
-               "serve/*", "core/*", "data/*", "perf/*", "ft/*",
+               "serve/*", "core/*", "data/*", "ft/*",
                "checkpoint/*"),
         checker=_check_raw_prngkey,
         fix_hint="fold_in from a key handed down by the boundary "
                  "(train/launch/engine config seed)",
         doc="PRNG keys are created at boundary modules only; per-request "
             "streams fold_in from per-request state",
-    ),
-    Rule(
-        id="no-deprecated-surface",
-        scope=("*",),
-        exclude=("train/*",),
-        checker=_check_deprecated_surface,
-        fix_hint="use repro.serve.InferenceEngine (submit/step/run)",
-        doc="internal code does not call the deprecated lock-step "
-            "train.serve.Server shim",
     ),
 ):
     register(_rule)

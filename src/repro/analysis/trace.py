@@ -343,7 +343,7 @@ def _check_no_host_callback(target, art) -> Iterator[Violation]:
 def _check_barrier_survives_fusion(target, art) -> Iterator[Violation]:
     if art.hlo is None:
         return
-    from repro.perf.hlo_analysis import parse_hlo
+    from repro.analysis.hlo import parse_hlo
 
     pre_text, opt_text = art.hlo()
     pre = parse_hlo(pre_text).opcode_counts()

@@ -6,13 +6,10 @@ from repro.configs.base import (  # noqa: F401
     EncoderConfig,
     MLAConfig,
     MoEConfig,
-    SHAPES,
     SSMConfig,
-    ShapeSpec,
     VisionStubConfig,
     XLSTMConfig,
     get_config,
     get_smoke,
     list_archs,
-    shape_applicable,
 )

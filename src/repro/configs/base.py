@@ -142,16 +142,6 @@ class ArchConfig:
         """Vocab padded to a multiple of 2048 for clean 16-way TP sharding."""
         return -(-self.vocab_size // 2048) * 2048
 
-    @property
-    def subquadratic(self) -> bool:
-        """True if long_500k is runnable (no full-attention O(S^2) layer at
-        5e5 sequence length, or attention windows bound the KV cost)."""
-        if self.family in ("ssm",):
-            return True
-        if self.family == "hybrid" and self.sliding_window > 0:
-            return True
-        return False
-
     def replace(self, **kw) -> "ArchConfig":
         return dataclasses.replace(self, **kw)
 
@@ -210,33 +200,6 @@ class ArchConfig:
                 total += self.encoder.n_layers * enc_layer + self.n_layers * cross
             active = total
         return {"total": float(total), "active": float(active)}
-
-
-# ---------------------------------------------------------------------------
-# Shapes
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    kind: str          # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-
-SHAPES: Dict[str, ShapeSpec] = {
-    "train_4k": ShapeSpec("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeSpec("prefill_32k", "prefill", 32768, 32),
-    "decode_32k": ShapeSpec("decode_32k", "decode", 32768, 128),
-    "long_500k": ShapeSpec("long_500k", "decode", 524288, 1),
-}
-
-
-def shape_applicable(cfg: ArchConfig, shape: ShapeSpec) -> Tuple[bool, str]:
-    """(runnable, reason). long_500k needs sub-quadratic attention."""
-    if shape.name == "long_500k" and not cfg.subquadratic:
-        return False, "full-attention arch: 512k KV decode is out of scope (DESIGN.md §5)"
-    return True, ""
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,7 @@ ssm_state=16. Sliding-window attention (1024) in all layers except the
 global-attention layers {0, 15, 31} (first/middle/last, per the paper).
 The per-layer attention and SSM outputs are each normalized and averaged
 before the output projection (the paper's fusion rule). Meta-tokens are
-omitted (noted in DESIGN.md §5) — they are a prompt-side additive feature
+omitted — they are a prompt-side additive feature
 orthogonal to the backbone shapes exercised here.
 
 Sub-quadratic: SWA bounds the attention cost, the SSM is O(S) — long_500k

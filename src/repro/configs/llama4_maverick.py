@@ -3,7 +3,7 @@
 [hf:meta-llama/Llama-4-*; unverified] 48L d_model=5120 40H (GQA kv=8)
 d_ff=8192 vocab=202048, MoE 128e top-1.
 
-Parameter-count derivation (DESIGN.md §5): "MoE 128e top-1" on every layer
+Parameter-count derivation: "MoE 128e top-1" on every layer
 with d_ff 8192 would give ~780B; the published Maverick interleaves MoE on
 every 2nd layer (interleave_moe_layer_step=2) with a shared expert
 (d_ff 8192) on MoE layers and a wider dense MLP (16384) on dense layers:

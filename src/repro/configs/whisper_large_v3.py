@@ -4,7 +4,7 @@
 d_ff=5120 vocab=51866. ``input_specs()`` provides precomputed frame
 embeddings [B, 1500, 1280] (the conv frontend output); decoder shapes follow
 the generic LM shape table (mechanical at 32k decode — the real model emits
-<=448 tokens; noted in DESIGN.md §5). GELU MLP, parametric LayerNorm,
+<=448 tokens). GELU MLP, parametric LayerNorm,
 learned positions (sinusoidal-vs-learned distinction immaterial for the
 backbone shapes; absolute learned embeddings used for both stacks).
 """

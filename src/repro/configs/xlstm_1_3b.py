@@ -22,9 +22,9 @@ CONFIG = ArchConfig(
     vocab_size=50304,
     norm="layernorm",
     mlp="none",
-    # chunk=512: §Perf I3b — halves the per-chunk C-state saves in the
+    # chunk=512 halves the per-chunk C-state saves in the
     # backward scan (the byte-dominant term) at 2x the (cheap) intra-chunk
-    # flops; see EXPERIMENTS.md
+    # flops
     xlstm=XLSTMConfig(slstm_every=8, mlstm_proj_factor=2.0,
                       mlstm_qk_factor=0.5, slstm_proj_factor=1.3333,
                       conv_kernel=4, chunk=512),

@@ -11,8 +11,8 @@ Two model families live here:
 
 2. ``ecm_tpu`` — the TPU adaptation. The memory hierarchy is
    VREG ← VMEM ← HBM; the unit of work is one VMEM block (BlockSpec tile)
-   instead of one cache line. The central *assumption inversion* (DESIGN.md
-   §7): on TPU the HBM→VMEM DMA overlaps with compute when the kernel is
+   instead of one cache line. The central *assumption inversion*: on TPU
+   the HBM→VMEM DMA overlaps with compute when the kernel is
    double-buffered, so
 
        T_db  = max(T_core, T_hbm)          (double-buffered, the default)
@@ -321,51 +321,7 @@ def ecm_tpu(machine: TPUMachine, kernel: TPUKernelBlock) -> TPUECMResult:
 
 
 # ===========================================================================
-# Part 3: roofline terms for whole-model steps (feeds perf/roofline.py)
-# ===========================================================================
-
-@dataclasses.dataclass(frozen=True)
-class RooflineTerms:
-    """Three-term roofline for one compiled (arch x shape x mesh) cell."""
-
-    flops: float
-    hbm_bytes: float
-    collective_bytes: float
-    chips: int
-    machine: TPUMachine = TPU_V5E
-
-    @property
-    def compute_s(self) -> float:
-        return self.flops / (self.chips * self.machine.mxu_bf16_tflops * 1e12)
-
-    @property
-    def memory_s(self) -> float:
-        return self.hbm_bytes / (self.chips * self.machine.hbm_gbs * 1e9)
-
-    @property
-    def collective_s(self) -> float:
-        bw = self.machine.ici_gbs_per_link * self.machine.ici_links * 1e9
-        return self.collective_bytes / (self.chips * bw)
-
-    @property
-    def dominant(self) -> str:
-        terms = {"compute": self.compute_s, "memory": self.memory_s,
-                 "collective": self.collective_s}
-        return max(terms, key=terms.get)
-
-    @property
-    def step_time_s(self) -> float:
-        """Optimistic fully-overlapped step time = max of the three terms."""
-        return max(self.compute_s, self.memory_s, self.collective_s)
-
-    def roofline_fraction(self, model_flops: float) -> float:
-        """Fraction of peak: useful-FLOPs-time / predicted step time."""
-        ideal = model_flops / (self.chips * self.machine.mxu_bf16_tflops * 1e12)
-        return ideal / self.step_time_s if self.step_time_s > 0 else 0.0
-
-
-# ===========================================================================
-# Part 4: compensation-scheme registry bridge
+# Part 3: compensation-scheme registry bridge
 # ===========================================================================
 #
 # The variant axis (naive / kahan / pairwise / dot2 / custom) is owned by
@@ -525,30 +481,6 @@ def expected_cost(scheme: Union[str, object], *, compute_dtype=None,
         scheme=sch.name, dot_adds=dot_adds, dot_muls=dot_muls,
         sum_adds=sum_adds, elem_bytes=elem_bytes, streams=streams,
         table_flops=sch.instruction_mix.flops)
-
-
-def predicted_us_per_call(scheme: Union[str, object], n: int, *,
-                          machine: TPUMachine = TPU_V5E,
-                          compute_dtype=None, streams: int = 2) -> float:
-    """ECM-predicted wall time (µs) for one length-``n`` reduction call.
-
-    Evaluates the TPU double-buffered model at block size ``n`` (one
-    block per call — the steady-state per-element rate times n) and
-    converts cycles to µs at the machine clock. This is the model column
-    of the ``ecm_model_error_<scheme>`` benchmark rows; the measured
-    column comes from the dot-grid timings in ``BENCH_*.json``.
-    """
-    res = ecm_tpu_for_scheme(machine, scheme, elems=n,
-                             compute_dtype=compute_dtype, streams=streams)
-    return res.t_db_cy / (machine.clock_ghz * 1e3)
-
-
-def model_relative_error(predicted_us: float, measured_us: float) -> float:
-    """|measured - predicted| / measured — the model-honesty scalar the
-    benchmark rows and the ROADMAP-item-5 autotuner report."""
-    if measured_us <= 0.0:
-        return float("inf")
-    return abs(measured_us - predicted_us) / measured_us
 
 
 # Named kernel constants, derived lazily (PEP 562 module __getattr__) from

@@ -1,13 +1,9 @@
 """Distribution: sharding rules, explicit collectives, gradient compression."""
 
 from repro.distributed.sharding import (  # noqa: F401
-    SERVE_RULES,
     TRAIN_RULES,
     Rules,
     activation_rules,
-    batch_shardings,
     constrain,
-    named_sharding,
     physical_spec,
-    tree_shardings,
 )

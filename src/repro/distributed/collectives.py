@@ -22,7 +22,7 @@ in launch/train.py). What lives here is the *explicitly managed* layer:
   identical data.
 * ``deterministic_mean`` — shard_map wrapper around the core compensated
   scalar reduction (bitwise run-to-run reproducible metrics regardless of
-  reduction order; DESIGN.md §3 item 4).
+  reduction order).
 * ``reduce_scatter_grads`` — spec helper: gradients of FSDP-sharded params
   should be produced reduce-scattered, not all-reduced; under pjit this is
   expressed through the output shardings (grads inherit param specs), so

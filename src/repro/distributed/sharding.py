@@ -3,22 +3,17 @@
 Models annotate parameters/caches with LOGICAL PartitionSpecs ("embed",
 "heads", "mlp", ...). This module maps them onto mesh axes per a rule
 table, with shape-aware degradation: a mapping is dropped when the dim is
-not divisible by the mesh-axis product (e.g. batch=1 on long_500k, or
+not divisible by the mesh-axis product (e.g. batch=1, or
 kv_heads=5 on a 16-way model axis) — replication instead of a hard error,
 mirroring how production frameworks degrade.
 
-Rule tables (the §Perf hillclimb mutates these):
+Rule table:
 
 TRAIN_RULES — DP over (pod, data) for batch; ZeRO-3/FSDP over data for the
   "embed" weight dim; TP over model for heads/mlp/vocab/expert; sequence-
   parallel activations ("seq" -> model) so archs whose head counts do not
   divide 16 (llama4 40H, hymba 25H, whisper 20H) still shard attention
   compute by q-position.
-
-SERVE_RULES — batch over (pod, data); KV-cache SEQUENCE over model
-  (flash-decoding style: per-shard softmax partials all-reduced by SPMD),
-  which scales decode for every arch regardless of head divisibility;
-  weights TP over model + "embed" over data (ZeRO-R style gather at use).
 """
 
 from __future__ import annotations
@@ -26,10 +21,9 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
-from typing import Any, Dict, Optional, Tuple, Union
+from typing import Dict, Optional, Tuple, Union
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -61,29 +55,6 @@ TRAIN_RULES = Rules("train", {
     "xl_inner": "model",
     "kv_seq": None,
     "kv_ring": None,
-    "frames": None,
-})
-
-# no-SP variant: sequence-local architectures (xLSTM's chunked recurrence)
-# lose the seq sharding at every chunk reshape anyway — each boundary then
-# costs a gather. Batch-sharded activations avoid them (§Perf I3c).
-TRAIN_NOSP_RULES = Rules("train_nosp",
-                         {**TRAIN_RULES.table, "seq": None})
-
-SERVE_RULES = Rules("serve", {
-    "batch": ("pod", "data"),
-    "seq": None,
-    "embed": "data",
-    "heads": "model",
-    "kv_heads": None,          # decode shards the cache by SEQUENCE instead
-    "mlp": "model",
-    "vocab": "model",
-    "expert": "model",
-    "moe_group": ("pod", "data"),
-    "kv_lora": None,
-    "xl_inner": "model",
-    "kv_seq": "model",         # flash-decoding: shard KV positions
-    "kv_ring": "model",        # ring windows shard like KV positions
     "frames": None,
 })
 
@@ -135,20 +106,6 @@ def physical_spec(mesh: Mesh, rules: Rules, logical: P,
     return P(*out)
 
 
-def named_sharding(mesh: Mesh, rules: Rules, logical: P,
-                   shape: Tuple[int, ...]) -> NamedSharding:
-    return NamedSharding(mesh, physical_spec(mesh, rules, logical, shape))
-
-
-def tree_shardings(mesh: Mesh, rules: Rules, spec_tree: Any,
-                   shape_tree: Any) -> Any:
-    """Build a NamedSharding tree from (logical spec tree, eval_shape tree)."""
-    is_spec = lambda s: isinstance(s, P)
-    return jax.tree.map(
-        lambda spec, shp: named_sharding(mesh, rules, spec, tuple(shp.shape)),
-        spec_tree, shape_tree, is_leaf=is_spec)
-
-
 # ---------------------------------------------------------------------------
 # Activation-constraint context (used by models via ``constrain``)
 # ---------------------------------------------------------------------------
@@ -177,22 +134,3 @@ def constrain(x: jax.Array, *logical: Optional[str]) -> jax.Array:
     spec = physical_spec(mesh, rules, P(*logical), tuple(x.shape))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
-
-# ---------------------------------------------------------------------------
-# Batch specs
-# ---------------------------------------------------------------------------
-
-BATCH_LOGICAL = {
-    "tokens": P("batch", None),
-    "labels": P("batch", None),
-    "loss_mask": P("batch", None),
-    "vision_embeds": P("batch", None, None),
-    "frames": P("batch", None, None),
-}
-
-
-def batch_shardings(mesh: Mesh, rules: Rules, batch_shapes: Dict[str, Any],
-                    ) -> Dict[str, NamedSharding]:
-    return {k: named_sharding(mesh, rules, BATCH_LOGICAL[k],
-                              tuple(v.shape))
-            for k, v in batch_shapes.items()}

@@ -17,9 +17,8 @@ package carries the core artifacts:
                     FMA-as-ADD trick). Emits the raw (s, c) output-tile
                     grids; single and batched (batch, mb, nb, ks) grids.
   flash_attention.py — fused flash attention with scheme-compensated
-                    online-softmax accumulators (the fix for the dominant
-                    roofline term found in EXPERIMENTS.md §Perf). Emits
-                    the raw (l, l_c, acc, acc_c) grids; the shared
+                    online-softmax accumulators (scores never leave VMEM).
+                    Emits the raw (l, l_c, acc, acc_c) grids; the shared
                     flash_block_update body is traced by the kernel AND
                     the ref oracle (bitwise by construction).
   engine.py       — the unified CompensatedReduction engine: one (s, c)

@@ -1,7 +1,7 @@
 """Pallas TPU flash-attention with compensated online softmax.
 
-Motivation (EXPERIMENTS.md §Perf): the dominant residual roofline term in
-every train/prefill cell is the materialized fp32 score/softmax buffer
+Motivation: the dominant residual roofline term in
+every train/prefill step is the materialized fp32 score/softmax buffer
 traffic — the textbook fix is a fused flash kernel (scores never leave
 VMEM). This kernel is that fix, with the paper's technique applied where
 it belongs inside it: the ONLINE-SOFTMAX ACCUMULATORS.

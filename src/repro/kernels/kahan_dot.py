@@ -1,6 +1,6 @@
 """Pallas TPU kernel for the compensated dot product — paper Fig. 1b.
 
-TPU adaptation of the paper's SIMD kernels (DESIGN.md §2):
+TPU adaptation of the paper's SIMD kernels:
 
 * The SIMD lane structure is the VPU's native (8, 128) tile; the paper's
   *unroll factor* U becomes the number of independent (8, 128) accumulator
@@ -15,7 +15,7 @@ TPU adaptation of the paper's SIMD kernels (DESIGN.md §2):
   ``_BLOCK_BYTES``, so a large block amortizes the fixed cost of a grid
   step while the tile sequence, and so the rounding, is that of one tile
   per step. HBM→VMEM transfers are double-buffered by the Pallas
-  pipeline — the ECM overlap inversion described in DESIGN.md §7.
+  pipeline — the ECM overlap inversion described in ``repro.core.ecm``.
 * The accumulation step is NOT hardcoded: the kernel body is one
   parameterized loop that calls ``scheme.mul_update`` from the
   compensation-scheme registry (``repro.kernels.schemes``) — naive,

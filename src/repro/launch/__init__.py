@@ -1,1 +1,1 @@
-"""Launchers: production mesh, dry-run, train/serve drivers."""
+"""Launchers: the train and serve drivers, and the compile cache they share."""

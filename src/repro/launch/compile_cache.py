@@ -1,7 +1,7 @@
 """JAX persistent compilation cache for the entry points.
 
 Every entry point (``chip_smoke.py``, ``repro.launch.serve``,
-``repro.launch.train``, ``benchmarks.run``) calls
+``repro.launch.train``, ``bench/harness.py``) calls
 ``enable_compile_cache()`` before it compiles anything. If
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and nothing
 else is set here. Otherwise the cache lives at ``.jax_cache/`` in the

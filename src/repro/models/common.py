@@ -2,8 +2,8 @@
 
 * ``stack_init`` — vmap a per-layer init over L keys -> params stacked with a
   leading "layers" axis (never sharded), ready for ``lax.scan`` over layers
-  (keeps the HLO size O(1) in depth — essential for 48-layer × 512-device
-  dry-run compiles on one CPU).
+  (keeps the HLO size O(1) in depth, so compile time does not grow with
+  the layer count).
 * ``chunked_ce_loss`` — the vocab matmul + cross-entropy evaluated in
   sequence chunks under ``jax.checkpoint`` with KAHAN-COMPENSATED chunk
   accumulation (paper technique, applied to the longest fp32 reduction in

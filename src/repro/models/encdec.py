@@ -5,7 +5,7 @@ of the conv frontend). The encoder is a full-attention non-causal stack;
 the decoder interleaves causal self-attention (KV-cached for serving) and
 cross-attention to the encoder memory (cross-K/V cached at prefill). RoPE
 stands in for learned absolute positions — immaterial for the backbone
-shapes (DESIGN.md §5).
+shapes.
 """
 
 from __future__ import annotations

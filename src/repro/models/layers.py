@@ -163,7 +163,7 @@ def attn_init(key, cfg: ArchConfig, *, cross: bool = False) -> Tuple[Params, Par
     dt = _dtype(cfg.param_dtype)
     ks = jax.random.split(key, 4)
     # shard heads over "heads" only when the production TP=16 divides them;
-    # otherwise replicate (DESIGN.md §5: hymba 25H, whisper 20H).
+    # otherwise replicate (hymba 25H, whisper 20H).
     hspec = "heads" if h % 16 == 0 else None
     kvspec = "kv_heads" if kv % 16 == 0 else None
     pq, sq = dense_init(ks[0], d, (h, dh), dtype=dt, bias=cfg.qkv_bias,

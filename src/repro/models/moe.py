@@ -1,6 +1,6 @@
 """Mixture-of-Experts layer with GROUP-LOCAL sort-based dispatch.
 
-Scalability notes (DESIGN.md §4):
+Scalability notes:
 
 * The classic one-hot dispatch einsum materializes [T, E, C] — at llama4
   scale (T=1M, E=128, C≈10k) that is O(10^12) elements. Unusable.

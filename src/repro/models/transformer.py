@@ -167,7 +167,7 @@ class TransformerLM:
                     cache_index=cache_index, chunk_valid=chunk_valid)
             # named for the remat policy: saving the (small) per-layer
             # attention output lets the backward pass recompute the fp32
-            # score/softmax chain ONCE instead of twice (§Perf I4)
+            # score/softmax chain ONCE instead of twice
             from jax.ad_checkpoint import checkpoint_name
             attn_out = checkpoint_name(attn_out, "attn_out")
             x = x + attn_out
@@ -202,7 +202,7 @@ class TransformerLM:
                                          chunk_valid=chunk_valid)
 
             if remat:
-                # plain full-recompute remat. Measured (§Perf I4): saving
+                # plain full-recompute remat. Measured: saving
                 # attn_out via save_only_these_names gives no byte-model
                 # win (the bwd-proper score chain is recomputed either
                 # way) while costing save memory — policy reverted.

@@ -19,8 +19,7 @@ model-level scan iterates groups of ``slstm_every`` blocks (params stacked
 Compensated-accumulation touchpoint (the paper-technique tie-in): chunk
 boundary folds of (C, n) use plain adds in fp32 — the compensated variant
 is exercised at the loss/optimizer level, not inside the recurrences (the
-stabilized exponentials dominate the error budget here; noted in
-DESIGN.md §5).
+stabilized exponentials dominate the error budget here).
 """
 
 from __future__ import annotations

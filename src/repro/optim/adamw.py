@@ -1,7 +1,7 @@
 """AdamW and Kahan-compensated AdamW (the paper's technique applied to the
 optimizer's long-horizon parameter accumulation).
 
-Motivation (DESIGN.md §3): with bf16 parameters, per-step updates are
+Motivation: with bf16 parameters, per-step updates are
 typically ~1e-3 of the parameter magnitude — far below bf16's 2^-8 relative
 resolution — so naive ``p += update`` silently drops most steps ("stale
 weights"). The classical fixes are fp32 master weights (+4 bytes/param).

@@ -1,4 +1,3 @@
-"""Training / serving drivers."""
+"""Training driver."""
 
 from repro.train.trainer import TrainConfig, Trainer, make_train_step  # noqa: F401
-from repro.train.serve import ServeConfig, Server, make_decode_step, make_prefill_step  # noqa: F401

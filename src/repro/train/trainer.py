@@ -12,7 +12,7 @@ small microbatch gradient lands on a large partial sum; the paper's kernel
 over microbatches instead of vector lanes). The optimizer update runs once
 per global step.
 
-PP note (DESIGN.md §4): the scan-over-layers structure is stage-sliceable
+PP note: the scan-over-layers structure is stage-sliceable
 (a pipeline stage = a contiguous slice of the stacked layer params), but
 the assigned production mesh has no stage axis, so PP is not mapped here.
 """

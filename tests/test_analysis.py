@@ -128,19 +128,6 @@ FIXTURES = [
             return jax.random.fold_in(base_key, request_id)
         """,
     ),
-    (
-        "no-deprecated-surface", "serve/x.py",
-        """\
-        from repro.train.serve import Server
-        def make(cfg):
-            return Server(cfg)
-        """,
-        """\
-        from repro.serve import InferenceEngine
-        def make(cfg, ec):
-            return InferenceEngine(cfg, ec)
-        """,
-    ),
 ]
 
 
@@ -371,7 +358,7 @@ def test_all_issue_rules_registered():
     expected = {"no-raw-psum", "no-legacy-mode-kwarg",
                 "no-uncompensated-reduction", "no-literal-interpret",
                 "no-hardcoded-accum-dtype", "no-host-sync-in-trace",
-                "no-raw-prngkey", "no-deprecated-surface"}
+                "no-raw-prngkey"}
     assert expected <= set(rules.names())
 
 

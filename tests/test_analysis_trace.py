@@ -17,6 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.analysis import lint_source, targets, trace
 from repro.analysis.__main__ import main as cli_main
+from repro.analysis.hlo import parse_hlo
 from repro.analysis.report import render_json
 
 
@@ -328,6 +329,86 @@ def test_barrier_fusion_fires_when_compensation_folded():
     found = _fired("trace-barrier-survives-fusion", ("hlo",),
                    _hlo_art(_PRE_HLO, _OPT_FOLDED))
     assert found and "folded" in found[0].message
+
+
+# ---------------------------------------------------------------------------
+# HLO op inventory: the parser both HLO audits count opcodes with
+# ---------------------------------------------------------------------------
+
+HLO_SAMPLE = """
+HloModule test, is_scheduled=true
+
+%body.1 (p.1: (s32[], f32[64,64])) -> (s32[], f32[64,64]) {
+  %p.1 = (s32[], f32[64,64]{1,0}) parameter(0)
+  %gte.1 = s32[] get-tuple-element(%p.1), index=0
+  %gte.2 = f32[64,64]{1,0} get-tuple-element(%p.1), index=1
+  %dot.1 = f32[64,64]{1,0} dot(%gte.2, %gte.2), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+  %ar.1 = f32[64,64]{1,0} all-reduce(%dot.1), replica_groups=[1,4]<=[4], to_apply=%add
+  ROOT %tuple.1 = (s32[], f32[64,64]{1,0}) tuple(%gte.1, %ar.1)
+}
+
+%cond.1 (p.2: (s32[], f32[64,64])) -> pred[] {
+  %p.2 = (s32[], f32[64,64]{1,0}) parameter(0)
+  %gte.3 = s32[] get-tuple-element(%p.2), index=0
+  %c.1 = s32[] constant(12)
+  ROOT %lt.1 = pred[] compare(%gte.3, %c.1), direction=LT
+}
+
+ENTRY %main.1 (a: f32[64,64]) -> f32[64,64] {
+  %a = f32[64,64]{1,0} parameter(0)
+  %c0 = s32[] constant(0)
+  %t0 = (s32[], f32[64,64]{1,0}) tuple(%c0, %a)
+  %w.1 = (s32[], f32[64,64]{1,0}) while(%t0), condition=%cond.1, body=%body.1, backend_config={"known_trip_count":{"n":"12"}}
+  ROOT %out = f32[64,64]{1,0} get-tuple-element(%w.1), index=1
+}
+"""
+
+# ``lowered.compiler_ir("hlo").as_hlo_text()`` of
+# ``jnp.sum(optimization_barrier(x) - x)``: bare headers, no % sigils.
+_PRE_OPT_SAMPLE = """\
+HloModule jit_f, entry_computation_layout={(f32[8]{0})->f32[]}
+
+region_0.1 {
+  reduce_sum.3 = f32[] parameter(0)
+  reduce_sum.4 = f32[] parameter(1)
+  ROOT reduce_sum.5 = f32[] add(reduce_sum.3, reduce_sum.4)
+}
+
+ENTRY main.2 {
+  x.1 = f32[8]{0} parameter(0)
+  optimization_barrier.1 = f32[8]{0} opt-barrier(x.1)
+  sub.1 = f32[8]{0} subtract(optimization_barrier.1, x.1)
+  constant.1 = f32[] constant(0)
+  ROOT reduce_sum.7 = f32[] reduce(sub.1, constant.1), dimensions={0}, to_apply=region_0.1
+}
+"""
+
+# Optimized modules print a /*index=N*/ comment every fifth tuple
+# element; the op is only parsed once the comments are stripped.
+_INDEX_COMMENT_SAMPLE = """\
+ENTRY %main.3 (p0: f32[]) -> (f32[], f32[], f32[], f32[], f32[], /*index=5*/f32[]) {
+  %p0 = f32[] parameter(0)
+  %n0 = f32[] negate(%p0)
+  ROOT %t = (f32[], f32[], f32[], f32[], f32[], /*index=5*/f32[]) tuple(%p0, %n0, %p0, %n0, %p0, /*index=5*/%n0)
+}
+"""
+
+
+@pytest.mark.parametrize("text,entry,computations,counts", [
+    (HLO_SAMPLE, "main.1", {"body.1", "cond.1", "main.1"},
+     {"parameter": 3, "get-tuple-element": 4, "dot": 1, "all-reduce": 1,
+      "tuple": 2, "constant": 2, "compare": 1, "while": 1}),
+    (_PRE_OPT_SAMPLE, "main.2", {"region_0.1", "main.2"},
+     {"parameter": 3, "add": 1, "opt-barrier": 1, "subtract": 1,
+      "constant": 1, "reduce": 1}),
+    (_INDEX_COMMENT_SAMPLE, "main.3", {"main.3"},
+     {"parameter": 1, "negate": 1, "tuple": 1}),
+], ids=["optimized-while", "pre-optimization", "index-comments"])
+def test_parse_hlo_opcode_counts(text, entry, computations, counts):
+    module = parse_hlo(text)
+    assert module.entry == entry
+    assert set(module.computations) == computations
+    assert module.opcode_counts() == counts
 
 
 # ---------------------------------------------------------------------------

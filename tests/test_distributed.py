@@ -1,5 +1,6 @@
 """Distribution-layer tests: sharding rule mapping (shape-aware
-degradation), compression codec + error feedback, HLO analyzer."""
+degradation), compression codec + error feedback, compensated
+cross-device reductions."""
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +9,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
 from repro.distributed import compression as comp
-from repro.perf import hlo_analysis
 
 
 # --- sharding rules ---------------------------------------------------------
@@ -128,46 +128,6 @@ def test_compressed_psum_single_device():
     np.testing.assert_allclose(np.asarray(run(x)), np.asarray(x), atol=0.05)
 
 
-# --- HLO analyzer -----------------------------------------------------------
-
-HLO_SAMPLE = """
-HloModule test, is_scheduled=true
-
-%body.1 (p.1: (s32[], f32[64,64])) -> (s32[], f32[64,64]) {
-  %p.1 = (s32[], f32[64,64]{1,0}) parameter(0)
-  %gte.1 = s32[] get-tuple-element(%p.1), index=0
-  %gte.2 = f32[64,64]{1,0} get-tuple-element(%p.1), index=1
-  %dot.1 = f32[64,64]{1,0} dot(%gte.2, %gte.2), lhs_contracting_dims={1}, rhs_contracting_dims={0}
-  %ar.1 = f32[64,64]{1,0} all-reduce(%dot.1), replica_groups=[1,4]<=[4], to_apply=%add
-  ROOT %tuple.1 = (s32[], f32[64,64]{1,0}) tuple(%gte.1, %ar.1)
-}
-
-%cond.1 (p.2: (s32[], f32[64,64])) -> pred[] {
-  %p.2 = (s32[], f32[64,64]{1,0}) parameter(0)
-  %gte.3 = s32[] get-tuple-element(%p.2), index=0
-  %c.1 = s32[] constant(12)
-  ROOT %lt.1 = pred[] compare(%gte.3, %c.1), direction=LT
-}
-
-ENTRY %main.1 (a: f32[64,64]) -> f32[64,64] {
-  %a = f32[64,64]{1,0} parameter(0)
-  %c0 = s32[] constant(0)
-  %t0 = (s32[], f32[64,64]{1,0}) tuple(%c0, %a)
-  %w.1 = (s32[], f32[64,64]{1,0}) while(%t0), condition=%cond.1, body=%body.1, backend_config={"known_trip_count":{"n":"12"}}
-  ROOT %out = f32[64,64]{1,0} get-tuple-element(%w.1), index=1
-}
-"""
-
-
-def test_hlo_analyzer_trip_weighting():
-    t = hlo_analysis.analyze_text(HLO_SAMPLE)
-    # 12 iterations x dot(64x64 @ 64x64) = 12 * 2*64^3 flops
-    assert t.flops == 12 * 2 * 64 ** 3
-    # 12 iterations of a 16 KiB all-reduce
-    assert t.coll["all-reduce"] == 12 * 64 * 64 * 4
-    assert t.bytes > 0
-
-
 def test_deterministic_mean_single_device():
     from repro.distributed.collectives import deterministic_mean
 
@@ -175,3 +135,20 @@ def test_deterministic_mean_single_device():
     v = jnp.asarray([3.5], jnp.float32)
     out = deterministic_mean(mesh, v, axis="data")
     assert float(out) == 3.5
+
+
+def test_compensated_psum_scalar_single_device():
+    from repro.core.kahan import compensated_psum_scalar
+
+    mesh = jax.make_mesh((1,), ("data",))
+
+    @jax.jit
+    def run(s, c):
+        return jax.shard_map(
+            lambda a, b: compensated_psum_scalar(a[0], b[0], "data"),
+            mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+            check_vma=False)(  # fold result is replicated by construction
+                s[None], c[None])
+
+    s, c = run(jnp.float32(1e8), jnp.float32(1.0))
+    assert float(s) + float(c) == 1e8 + 1.0

@@ -86,11 +86,3 @@ def test_tpu_sequential_kahan_is_compute_bound():
 def test_tpu_dot2_also_free():
     dot2 = ecm.ecm_tpu(ecm.TPU_V5E, ecm.DOT2_TPU)
     assert dot2.bound == "bandwidth"  # even 17 flops/elem hides under HBM
-
-
-def test_roofline_terms():
-    t = ecm.RooflineTerms(flops=1e15, hbm_bytes=1e13, collective_bytes=1e11,
-                          chips=256)
-    assert t.dominant == "memory"
-    assert t.compute_s < t.memory_s
-    assert 0 < t.roofline_fraction(5e14) <= 1.0

@@ -217,6 +217,13 @@ def test_sharded_merge_matches_single_device_on_2_devices():
     assert "OK" in res.stdout
 
 
+def test_local_process_sees_one_device():
+    """The XLA_FLAGS device-count override must NOT be global."""
+    import jax
+
+    assert len(jax.devices()) == 1
+
+
 # --- matmul on the engine contract -------------------------------------------
 
 def _mm_batch(b, m, k, n, seed=0):

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_smoke
 from repro.configs.base import (
     ArchConfig,
     EncoderConfig,
@@ -299,6 +300,34 @@ def test_per_request_seeds(tiny_model):
     solo = _solo_replay(cfg, ec, model, params, mk(0, seed=7))
     assert solo.tokens == served[0].tokens
     assert solo.telemetry == served[0].telemetry
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "qwen2.5-3b"])
+def test_smoke_preset_greedy_determinism(arch):
+    """A real smoke preset served greedily, two rows at a time: the same
+    prompts give the same tokens in a fresh engine, and different
+    prompts give different tokens."""
+    cfg = get_smoke(arch)
+    model = build_model(cfg)
+    params, _ = model.init(jax.random.key(0))
+    b, s, n_new = 2, 16, 6
+    ec = EngineConfig(max_slots=b, max_len=s + n_new)
+
+    def serve(seed):
+        rng = np.random.default_rng(seed)
+        prompts = rng.integers(0, cfg.vocab_size, (b, s)).astype(np.int32)
+        reqs = [Request(prompt=prompts[i], request_id=i,
+                        sampling=SamplingParams(temperature=0.0,
+                                                max_new_tokens=n_new))
+                for i in range(b)]
+        eng = InferenceEngine(cfg, ec, model=model, params=params)
+        served = eng.run(reqs)
+        return [served[i].tokens for i in range(b)]
+
+    first, again, other = serve(1), serve(1), serve(2)
+    assert first == again
+    assert all(len(t) == n_new and max(t) < cfg.padded_vocab for t in first)
+    assert first != other
 
 
 # ---------------------------------------------------------------------------
